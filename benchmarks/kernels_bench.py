@@ -7,7 +7,7 @@ metric so the perf trajectory of each tier is machine-readable
 * ``ref`` — the jnp reference contraction exactly as the dispatch layer's
   ``ref`` path runs it (eager, the golden oracle);
 * ``compiled`` — the tier the dispatch layer picks by default: the Mosaic
-  Pallas kernel on TPU/GPU, the XLA-jitted tiled twin elsewhere, with
+  Pallas kernel on TPU, the XLA-jitted tiled twin elsewhere, with
   tiles chosen by a **measured** :func:`~repro.kernels.cl.autotune.search_tiles`
   run (the timings land in the JSON next to the winner);
 * ``interpret`` — the Python-speed Pallas interpreter (validation only, so

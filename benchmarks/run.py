@@ -22,6 +22,8 @@ MODULES = (
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     failures = []
     for name in MODULES:
         print(f"# === benchmarks.{name} ===", flush=True)

@@ -45,7 +45,7 @@ def provenance() -> dict:
     work tree), UTC timestamp, jax backend, and the default kernel path
     the dispatch layer picks on this backend (the
     :data:`repro.kernels.cl.ops.KERNEL_PATHS` taxonomy — Mosaic Pallas on
-    TPU/GPU, the XLA tiled twin elsewhere)."""
+    TPU, the XLA tiled twin elsewhere)."""
     import jax
     from repro.kernels.cl.ops import default_kernel_path
     backend = jax.default_backend()

@@ -17,7 +17,6 @@ import jax                      # noqa: E402
 import jax.numpy as jnp         # noqa: E402
 import numpy as np              # noqa: E402
 from jax.sharding import PartitionSpec as P   # noqa: E402
-from jax.experimental.shard_map import shard_map  # noqa: E402
 
 import repro.core as C          # noqa: E402
 
@@ -78,11 +77,12 @@ def main():
         all_wts = jax.lax.all_gather(wts, "sensor")            # (p, dmax)
         return all_est[None], all_wts[None]
 
-    fn = shard_map(sensor_program, mesh=mesh,
-                   in_specs=(P("sensor"), P("sensor"), P("sensor")),
-                   out_specs=(P("sensor"), P("sensor")))
+    fn = jax.shard_map(sensor_program, mesh=mesh,
+                       in_specs=(P("sensor"), P("sensor"), P("sensor")),
+                       out_specs=(P("sensor"), P("sensor")),
+                       check_vma=False)
     est, wts = jax.jit(fn)(jnp.asarray(Z), jnp.asarray(xi), jnp.asarray(M))
-    est, wts = np.asarray(est[0]), np.asarray(wts[0])
+    est, wts = np.asarray(est)[0], np.asarray(wts)[0]
 
     # --- per-edge consensus (every sensor can do this locally) ------------
     theta_max = np.zeros(g.n_params)

@@ -58,7 +58,6 @@ from typing import Dict, List, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.cl.epilogues import get_epilogue
@@ -98,6 +97,20 @@ def _backtrack_step(objective, W, dirn, g, max_step):
     vals = jnp.where(jnp.isfinite(vals), vals, -jnp.inf)
     best = jnp.argmax(vals, axis=0)                          # (k,)
     return steps[best, jnp.arange(k)]
+
+
+def _float32_contractions(fn):
+    """Trace ``fn`` with every matmul/einsum at ``Precision.HIGHEST``.
+
+    The TPU's default contraction precision rounds float32 operands to
+    bfloat16; a float32 plan must mean float32 on every backend. CPU
+    contractions are exact at any setting, so CPU results are unchanged.
+    """
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+    return traced
 
 
 def _pad_degree(deg: int) -> int:
@@ -323,12 +336,14 @@ def _channel_ops(family, Zb, base, xi, sw, weighted, denom):
         score_matrix, newton_stats
 
 
+@_float32_contractions
 def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
                        include_singleton: bool, n_iter: int,
                        weighted: bool = False, guarded: bool = False,
                        family=ISING, tol: float = 2e-6,
                        ridge: float = 1e-8, max_step: float = 5.0,
-                       want_influence: bool = True):
+                       want_influence: bool = True,
+                       axis_name: Optional[str] = None):
     """Solve every node of one degree bucket in a single XLA program.
 
     X: (n, p) samples; nodes: (k,); nbrs: (k, deg_pad); mask: (k, deg_pad);
@@ -356,6 +371,12 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     outputs). A node whose weights sum to zero (nothing observed yet)
     stays at W0 untouched by data: its gradient vanishes and the guarded
     denominator keeps it finite.
+
+    ``axis_name`` names the mesh axis when this runs as one shard of a
+    bucket: the convergence test then takes the step's max over all
+    shards, so every shard runs the iteration count the whole bucket
+    would run on one device and the result does not depend on the
+    device count.
     """
     n = X.shape[0]
     Zb, xi, base, cmask = _bucket_design(family, X, nodes, nbrs, mask,
@@ -416,6 +437,8 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
         else:
             step = dirn
         delta = jnp.max(jnp.abs(step))
+        if axis_name is not None:
+            delta = jax.lax.pmax(delta, axis_name)
         return W - step, it + 1, delta
 
     W, iters, _ = jax.lax.while_loop(cond, newton_step, (W0, 0, jnp.inf))
@@ -479,7 +502,10 @@ def _solve_bucket_sharded(X, nodes, nbrs, mask, offsets, W0, sw,
 
     The bucket's k per-node problems are embarrassingly parallel, so each
     device solves its contiguous slice of the (padded) node axis against
-    the replicated sample pool — no collectives at all. On a one-device
+    the replicated sample pool. The only collective is one scalar max per
+    Newton iteration, which keeps every shard on the bucket-wide stopping
+    iteration (so the result does not depend on the device count). On a
+    one-device
     mesh (the host mesh) the single shard is the whole bucket and the
     computation is identical to :func:`_solve_bucket` op for op, which is
     what makes the single-device fallback numerically exact. The caller
@@ -489,14 +515,14 @@ def _solve_bucket_sharded(X, nodes, nbrs, mask, offsets, W0, sw,
     body = functools.partial(
         _solve_bucket_impl, include_singleton=include_singleton,
         n_iter=n_iter, weighted=weighted, guarded=guarded, family=family,
-        want_influence=want_influence)
+        want_influence=want_influence, axis_name="data")
     data = P("data")
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), data, data, data, data, data,
                   data if weighted else P()),
         out_specs=(data, data, data, data, data, data),
-        check_rep=False,
+        check_vma=False,
     )(X, nodes, nbrs, mask, offsets, W0, sw)
 
 
@@ -616,7 +642,9 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
 
     Observability: ``recorder`` (a :mod:`repro.telemetry` recorder; the
     allocation-free ``NULL_RECORDER`` when None) gets one ``bucket_solve``
-    span per degree bucket with Newton-iteration histograms; ``stats``
+    span per degree bucket with Newton-iteration histograms and, on a
+    mesh, one ``engine.shard_rows`` observation per device (the bucket
+    rows that device solved, tagged ``deg_pad`` and ``device``); ``stats``
     (a caller-provided dict) receives the compile-time split —
     ``stats["compile_s"]`` accumulates the wall seconds of bucket
     dispatches that triggered a compilation (the first-dispatch path) and
@@ -670,6 +698,11 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
                 include_singleton, n_iter, weighted,
                 warm_start is not None, family, mesh,
                 want_influence=want_influence)
+            if rec.enabled:
+                # bucket rows (padding included) each device solved
+                for s in W.addressable_shards:
+                    rec.observe("engine.shard_rows", int(s.data.shape[0]),
+                                deg_pad=b.deg_pad, device=int(s.device.id))
         W, H, J, V, S = (np.asarray(W)[:k], np.asarray(H)[:k],
                          np.asarray(J)[:k], np.asarray(V)[:k],
                          np.asarray(S)[:k])
@@ -703,11 +736,13 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
 
 
 # ------------------------------------------------------- proximal updates
+@_float32_contractions
 def _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho,
                             tbar, include_singleton: bool, n_iter: int,
                             weighted: bool = False, family=ISING,
                             tol: float = 2e-6, ridge: float = 1e-8,
-                            max_step: float = 5.0):
+                            max_step: float = 5.0,
+                            axis_name: Optional[str] = None):
     """ADMM primal update for a whole degree bucket in one XLA program.
 
     Maximizes, per node,  ``l^i(w) - lam'w - sum_a rho_a (w_a - tbar_a)^2/2``
@@ -716,7 +751,7 @@ def _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho,
     the prox terms only shift the gradient by ``-lam - rho*(w - tbar)`` and
     the Hessian by ``-diag(rho)``, so the bucket stays uniformly negative
     definite. lam, rho, tbar: (k, d*C) with zeros on padded coordinates.
-    Returns W only.
+    ``axis_name`` as in :func:`_solve_bucket_impl`. Returns W only.
     """
     n = X.shape[0]
     Zb, xi, base, cmask = _bucket_design(family, X, nodes, nbrs, mask,
@@ -767,6 +802,8 @@ def _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho,
             lambda: _backtrack_step(objective, W, dirn, g, max_step),
             lambda: dirn)
         delta = jnp.max(jnp.abs(step))
+        if axis_name is not None:
+            delta = jax.lax.pmax(delta, axis_name)
         return W - step, it + 1, delta
 
     W, _, _ = jax.lax.while_loop(cond, newton_step, (W0, 0, jnp.inf))
@@ -795,17 +832,17 @@ def _solve_bucket_prox_sharded(X, nodes, nbrs, mask, offsets, W0, sw, lam,
                                family=ISING, mesh=None):
     """Mesh-sharded proximal bucket solve — the ADMM-primal twin of
     :func:`_solve_bucket_sharded` (same data-axis node sharding, replicated
-    sample pool, no collectives)."""
+    sample pool, one scalar max per Newton iteration)."""
     body = functools.partial(
         _solve_bucket_prox_impl, include_singleton=include_singleton,
-        n_iter=n_iter, weighted=weighted, family=family)
+        n_iter=n_iter, weighted=weighted, family=family, axis_name="data")
     data = P("data")
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), data, data, data, data, data,
                   data if weighted else P(), data, data, data),
         out_specs=data,
-        check_rep=False,
+        check_vma=False,
     )(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho, tbar)
 
 

@@ -12,7 +12,7 @@ registry keyed by ``ModelFamily.kernel_kind``:
 * :mod:`.ref` — pure-jnp oracles for everything;
 * :mod:`.newton` — the fused Newton-step entry point emitting score + Gram
   directly in the degree-bucket ``(k, C, d)`` layout ``core/batched.py``
-  consumes, with lane-aligned padding of the tiny ``d*C`` output axis;
+  consumes;
 * :mod:`.tiled` — XLA-compiled CPU twins of the fused kernels (Pallas is
   interpret-only on CPU); the compiled-CPU dispatch tier;
 * :mod:`.autotune` — bounded tile-size search keyed by
@@ -26,7 +26,7 @@ registry keyed by ``ModelFamily.kernel_kind``:
 * :mod:`.family` — adapters from a :class:`ModelFamily` + graph + flat theta
   to kernel inputs, and the fused flat pseudo-score the streaming stack
   uses;
-* :mod:`.ops` — backend-aware dispatch (Mosaic on TPU/GPU, the compiled
+* :mod:`.ops` — backend-aware dispatch (Mosaic on TPU, the compiled
   tiled twins on CPU, ref / interpret on request) with tuned tiles and
   trace-time telemetry tags.
 
@@ -39,8 +39,7 @@ from .autotune import (CHUNK_MIN_N, KERNEL_OPS, TileConfig, cache_snapshot,
 from .epilogues import (Epilogue, get_epilogue, register_epilogue,
                         registered_kinds)
 from .kernel import cl_logits, cl_score_channels, ising_cl_logits
-from .newton import (bucket_newton_stats, bucket_newton_stats_ref,
-                     lane_padded_width)
+from .newton import bucket_newton_stats, bucket_newton_stats_ref
 from .ops import (KERNEL_PATHS, bucket_newton_stats_op, conditional_logits_op,
                   default_kernel_path, resolve_kernel_path,
                   score_stats_channels_op, score_stats_op)
@@ -59,7 +58,7 @@ __all__ = [
     "ising_cl_score", "ising_cl_score_padded", "KERNEL_KINDS",
     "cl_score_ref", "cl_score_channels_ref", "cl_logits_ref",
     "ising_cl_logits_ref", "ising_cl_score_ref",
-    "bucket_newton_stats", "bucket_newton_stats_ref", "lane_padded_width",
+    "bucket_newton_stats", "bucket_newton_stats_ref",
     "cl_score_channels_tiled", "bucket_newton_stats_tiled",
     "conditional_logits_op", "score_stats_op", "score_stats_channels_op",
     "bucket_newton_stats_op", "KERNEL_PATHS", "default_kernel_path",

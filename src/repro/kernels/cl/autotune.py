@@ -23,7 +23,7 @@ newton op. The cache round-trips through JSON (:func:`save_cache` /
 file lazily on first lookup and appends every search result to it.
 
 Search is bounded by construction: candidate lists are a handful of
-lane-friendly configs per (op, backend), every candidate is validated by
+MXU-aligned configs per (op, backend), every candidate is validated by
 :func:`validate_tile_config` before it is timed, and ties break toward the
 earliest candidate so two same-key searches agree bit-for-bit.
 """
@@ -66,14 +66,11 @@ class TileConfig:
         order.
     bn : output-column tile of the score kernel (ignored by newton).
     bk : contraction tile of the score kernel (ignored by newton).
-    lane : target lane width the newton kernel pads its tiny ``d*C`` output
-        axis up to (``None`` = no padding; the Mosaic path wants 128).
     """
 
     bm: Optional[int] = 128
     bn: int = 128
     bk: int = 128
-    lane: Optional[int] = None
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -81,11 +78,7 @@ class TileConfig:
     @classmethod
     def from_dict(cls, d: dict) -> "TileConfig":
         return cls(bm=d.get("bm"), bn=int(d.get("bn", 128)),
-                   bk=int(d.get("bk", 128)), lane=d.get("lane"))
-
-
-def _is_pow2(v: int) -> bool:
-    return v >= 1 and (v & (v - 1)) == 0
+                   bk=int(d.get("bk", 128)))
 
 
 def validate_tile_config(cfg: TileConfig, op: str,
@@ -93,9 +86,10 @@ def validate_tile_config(cfg: TileConfig, op: str,
     """Reject tile configs the kernels cannot run; returns ``cfg``.
 
     ``compiled=True`` applies the Mosaic (real-TPU) constraints on top of
-    the structural ones: 128-multiple lane tiles and an explicit (8-aligned)
-    sample tile. Interpret mode and the compiled-CPU twins only need
-    positive sizes.
+    the structural ones: 128-multiple score lane tiles and an explicit
+    (8-aligned) sample tile, 128-aligned for newton, whose blocks carry
+    the sample axis on the lanes. Interpret mode and the compiled-CPU
+    twins only need positive sizes.
     """
     if op not in KERNEL_OPS:
         raise ValueError(f"unknown kernel op {op!r}; choose from "
@@ -109,24 +103,19 @@ def validate_tile_config(cfg: TileConfig, op: str,
     for name, v in (("bn", cfg.bn), ("bk", cfg.bk)):
         if not isinstance(v, int) or v < 1:
             raise ValueError(f"{name} must be a positive int, got {v!r}")
-    if cfg.lane is not None and (
-            not isinstance(cfg.lane, int) or not _is_pow2(cfg.lane)
-            or not 8 <= cfg.lane <= 1024):
-        raise ValueError(f"lane must be a power of two in [8, 1024] or "
-                         f"None, got {cfg.lane!r}")
     if compiled:
         if cfg.bm is None or cfg.bm % 8:
             raise ValueError(
                 f"compiled Pallas path needs an explicit 8-aligned sample "
                 f"tile, got bm={cfg.bm!r}")
+        if op == "newton" and cfg.bm % 128:
+            raise ValueError(
+                f"compiled newton kernel tiles the sample axis on the "
+                f"lanes and needs a 128-multiple bm, got bm={cfg.bm!r}")
         if op == "score" and (cfg.bn % 128 or cfg.bk % 128):
             raise ValueError(
                 f"compiled score kernel needs 128-multiple lane tiles, got "
                 f"bn={cfg.bn} bk={cfg.bk}")
-        if op == "newton" and (cfg.lane is None or cfg.lane % 128):
-            raise ValueError(
-                f"compiled newton kernel needs a 128-multiple lane target, "
-                f"got lane={cfg.lane!r}")
     return cfg
 
 
@@ -211,17 +200,16 @@ def _default_tiles(op: str, *, n: int, p: int, C: int,
                    backend: str) -> TileConfig:
     """Shape heuristic used when nothing tuned is cached. Deterministic.
 
-    TPU/GPU: MXU-aligned 128s everywhere, lane-pad the newton output axis.
+    TPU: MXU-aligned 128s everywhere.
     CPU: the compiled-CPU twins — whole-axis (== the jnp reference
     contraction, golden-bit-stable) below :data:`CHUNK_MIN_N` samples,
     cache-sized 1024-sample chunks above it.
     """
-    if backend in ("tpu", "gpu"):
-        return TileConfig(bm=128, bn=128, bk=128,
-                          lane=128 if op == "newton" else None)
+    if backend == "tpu":
+        return TileConfig(bm=128, bn=128, bk=128)
     if op == "newton" and n >= CHUNK_MIN_N:
-        return TileConfig(bm=1024, lane=None)
-    return TileConfig(bm=None, lane=None)
+        return TileConfig(bm=1024)
+    return TileConfig(bm=None)
 
 
 def candidate_tiles(op: str, *, n: int, p: int, C: int,
@@ -229,16 +217,15 @@ def candidate_tiles(op: str, *, n: int, p: int, C: int,
     """The bounded search space for one key, heuristic default first.
 
     Small by design — the tuner is a measured tiebreak between a handful of
-    lane-friendly configs, not a general scheduler. Candidates whose chunk
+    MXU-aligned configs, not a general scheduler. Candidates whose chunk
     would exceed the sample axis are dropped (they alias the whole-axis
     config).
     """
     backend = backend or jax.default_backend()
     default = _default_tiles(op, n=n, p=p, C=C, backend=backend)
-    if backend in ("tpu", "gpu"):
+    if backend == "tpu":
         if op == "newton":
-            cands = [default] + [TileConfig(bm=bm, lane=128)
-                                 for bm in (256, 512)]
+            cands = [default] + [TileConfig(bm=bm) for bm in (256, 512)]
         else:
             cands = [default,
                      TileConfig(bm=256, bn=128, bk=128),
@@ -256,8 +243,7 @@ def candidate_tiles(op: str, *, n: int, p: int, C: int,
         if c not in seen:
             seen.add(c)
             out.append(validate_tile_config(c, op,
-                                            compiled=backend in
-                                            ("tpu", "gpu")))
+                                            compiled=backend == "tpu"))
     return tuple(out)
 
 
@@ -317,7 +303,7 @@ def search_tiles(op: str, *, n: int, p: int, C: int,
         candidate_tiles(op, n=n, p=p, C=C, backend=backend)
     if not cands:
         raise ValueError(f"no tile candidates for key {key!r}")
-    compiled = backend in ("tpu", "gpu")
+    compiled = backend == "tpu"
     timings: Dict[str, float] = {}
     best, best_cost = None, None
     for cfg in cands:

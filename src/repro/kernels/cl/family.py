@@ -11,11 +11,10 @@ from __future__ import annotations
 
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import cl_score_channels
+from .kernel import cl_score_channels, target_platform
 
 
 def family_kernel_inputs(family, graph, theta, X):
@@ -44,7 +43,7 @@ def family_score_stats(family, graph, theta, X, *,
 
     ``use_pallas=None`` picks the backend default through the dispatch
     layer (:func:`repro.kernels.cl.ops.score_stats_channels_op`): the
-    compiled Mosaic kernel on TPU/GPU, the XLA-compiled tiled twin
+    compiled Mosaic kernel on TPU, the XLA-compiled tiled twin
     elsewhere — and records the resolved path in telemetry. ``use_pallas=
     True`` forces the Pallas kernel (``interpret=None`` compiles where the
     backend can, interpret mode on CPU or on explicit ``interpret=True`` —
@@ -59,7 +58,7 @@ def family_score_stats(family, graph, theta, X, *,
                                        use_pallas=use_pallas,
                                        interpret=interpret)
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu", "gpu")
+        interpret = target_platform() != "tpu"
     return cl_score_channels(F, theta_c, mask, bias,
                              kind=family.kernel_kind,
                              interpret=interpret)

@@ -38,9 +38,11 @@ tunable through a :class:`~repro.kernels.cl.autotune.TileConfig` (static
 every axis is zero-padded up to the tile grid and sliced back, and the
 padding is provably invisible (zero feature rows/columns contribute
 nothing to any contraction; the edge-tile hypothesis properties pin it).
-``interpret=None`` derives from the backend: compiled on TPU/GPU,
-interpret (the Python-speed validation mode) elsewhere — Pallas cannot
-compile on CPU, where the dispatch layer uses :mod:`.tiled` instead.
+``interpret=None`` derives from the backend: compiled on TPU, interpret
+(the Python-speed validation mode) elsewhere — Pallas cannot compile on
+CPU, where the dispatch layer uses :mod:`.tiled` instead. Float32 operands
+contract at ``Precision.HIGHEST``, so a float32 plan means float32 on the
+TPU too (the MXU default rounds operands to bfloat16).
 """
 from __future__ import annotations
 
@@ -58,10 +60,25 @@ from .epilogues import require_epilogue
 BM, BN, BK = 128, 128, 128
 
 
+def target_platform() -> str:
+    """Platform the computation being traced will run on: that of the
+    ``jax.default_device`` in scope when one is set (so a host-CPU run on a
+    TPU machine takes the CPU paths), else the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
+def _precision(dtype):
+    """Dot precision for operands of ``dtype``: HIGHEST for float32."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
 def _resolve(interpret: Optional[bool], tiles):
     """(interpret, bm, bn, bk) trace-time constants from the static args."""
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu", "gpu")
+        interpret = target_platform() != "tpu"
     if tiles is None:
         return interpret, BM, BN, BK
     bm = BM if tiles.bm is None else int(tiles.bm)
@@ -79,8 +96,9 @@ def _logits_kernel(f_ref, theta_ref, mask_ref, bias_ref, out_ref, acc_ref):
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     masked = theta_ref[...] * mask_ref[...][None]    # VPU fuse, no HBM trip
+    prec = _precision(f_ref.dtype)
     for c in range(C):                               # static channel unroll
-        acc_ref[c] += jnp.dot(f_ref[c], masked[c],
+        acc_ref[c] += jnp.dot(f_ref[c], masked[c], precision=prec,
                               preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -98,7 +116,7 @@ def cl_logits(F, theta, mask, bias, *, interpret: Optional[bool] = None,
     F: (C, n, p); theta: (C, p, p); mask: (p, p); bias: (C, p). Returns
     eta of shape (C, n, p) in F.dtype. Shapes are padded to the tile grid
     internally (128s by default; ``tiles`` overrides). ``interpret=None``
-    derives from the backend — compiled on TPU/GPU, interpret elsewhere.
+    derives from the backend — compiled on TPU, interpret elsewhere.
     """
     interpret, bm, bn, bk = _resolve(interpret, tiles)
     C, n, p = F.shape
@@ -168,8 +186,8 @@ def _score_kernel_c1(x_ref, theta_ref, mask_ref, bias_ref,
 
     xstrip_ref[:, pl.ds(k * bk, bk)] = x_ref[...].astype(jnp.float32)
     masked = theta_ref[...] * mask_ref[...]          # VPU fuse, no HBM trip
-    acc_ref[...] += jnp.dot(x_ref[...], masked,
-                            preferred_element_type=jnp.float32)
+    acc_ref[...] += jnp.dot(x_ref[...], masked, precision=_precision(
+        x_ref.dtype), preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
     def _epilogue():
@@ -179,6 +197,7 @@ def _score_kernel_c1(x_ref, theta_ref, mask_ref, bias_ref,
         r = epilogue.residual(xj[None], eta[None])[0]
         r_ref[...] = r.astype(r_ref.dtype)
         s_ref[...] += jnp.dot(r.T, xstrip_ref[...],
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
     @pl.when((k == nk - 1) & (i == ni - 1))
@@ -208,8 +227,9 @@ def _score_kernel(f_ref, theta_ref, mask_ref, bias_ref,
     # stash this sample-tile's F strip so the Gram contraction stays on-chip
     fstrip_ref[:, :, pl.ds(k * bk, bk)] = f_ref[...].astype(jnp.float32)
     masked = theta_ref[...] * mask_ref[...][None]    # VPU fuse, no HBM trip
+    prec = _precision(f_ref.dtype)
     for c in range(C):                               # static channel unroll
-        acc_ref[c] += jnp.dot(f_ref[c], masked[c],
+        acc_ref[c] += jnp.dot(f_ref[c], masked[c], precision=prec,
                               preferred_element_type=jnp.float32)
 
     @pl.when(k == nk - 1)
@@ -223,6 +243,7 @@ def _score_kernel(f_ref, theta_ref, mask_ref, bias_ref,
         for c in range(C):
             for e in range(C):
                 s_ref[c, e] += jnp.dot(r[c].T, fstrip_ref[e],
+                                       precision=jax.lax.Precision.HIGHEST,
                                        preferred_element_type=jnp.float32)
 
     @pl.when((k == nk - 1) & (i == ni - 1))
@@ -243,7 +264,7 @@ def cl_score_channels(F, theta, mask, bias, *, kind: str,
     cross-channel score Gram S of shape (C, C, p, p) in float32 with
     ``S[c, e] = r_c^T F_e / n``.
 
-    ``interpret=None`` derives from the backend (compiled on TPU/GPU,
+    ``interpret=None`` derives from the backend (compiled on TPU,
     interpret — the Python-speed validation mode — elsewhere); ``tiles``
     is an optional :class:`~repro.kernels.cl.autotune.TileConfig`
     overriding the 128-aligned defaults. Shapes need not divide the tiles:

@@ -20,16 +20,13 @@ contraction and the score/Gram contraction:
   (including the C = 1 single-channel fast path), so swapping the engine
   onto this entry point is bit-stable — the 1e-10 golden fixtures pin it.
 * :func:`bucket_newton_stats` — the Pallas kernel: grid over (bucket node,
-  sample tile), design slab stashed in VMEM, epilogue residual + curvature
-  on the VPU, g and K accumulated on-chip across sample tiles. ``d`` and
-  ``d*C`` are the tiny per-node design dims (engine buckets pad degree to
-  powers of four), so the sample axis is the only tiled one. A
-  :class:`~repro.kernels.cl.autotune.TileConfig` supplies the sample tile
-  (``bm``) and, for real hardware, a ``lane`` target the tiny ``d*C``
-  output axis is zero-padded up to (128 = the TPU register lane width):
-  padded design rows are zero, so every score and Gram term they touch
-  vanishes identically and the outputs are sliced back — lane alignment is
-  provably invisible (the edge-tile/lane hypothesis properties pin it).
+  sample tile), epilogue residual + curvature on the VPU, g and K
+  accumulated on-chip across sample tiles. ``d`` and ``d*C`` are the tiny
+  per-node design dims (engine buckets pad degree to powers of four), so
+  the sample axis is the only tiled one; every other block dim equals its
+  full array dim, which is what the TPU lowering accepts for dims that are
+  not (8, 128)-aligned. A :class:`~repro.kernels.cl.autotune.TileConfig`
+  supplies the sample tile (``bm``).
 
 Both dispatch on the static epilogue ``kind``; coordinate-major flat layout
 ``[(d0,c0), (d0,c1), ..., (d1,c0), ...]`` matches ``family.beta`` exactly.
@@ -37,14 +34,15 @@ Both dispatch on the static epilogue ``kind``; coordinate-major flat layout
 from __future__ import annotations
 
 import functools
-import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .epilogues import require_epilogue
+from .kernel import target_platform
 
 BNK = 128   # default sample-axis tile
 
@@ -104,52 +102,46 @@ def bucket_newton_stats_ref(kind: str, Zb, base, xi, W, sw=None):
 # ------------------------------------------------------------ pallas kernel
 def _newton_kernel(z_ref, base_ref, xi_ref, sw_ref, w_ref, g_ref, k_ref, *,
                    kind: str, weighted: bool):
+    """One (bucket node, sample tile) grid step; every value stays >= 2-D.
+
+    Refs (leading bucket axis squeezed by the BlockSpecs): z (C, d, bm),
+    base (C, 1, bm), xi / sw (1, bm), w (C, 1, d) channel-major; outputs
+    g (C, 1, d) and K (C, C, d, d) accumulate across sample tiles.
+    """
     t = pl.program_id(1)
     ep = require_epilogue(kind)
-    C, d = z_ref.shape[1], z_ref.shape[2]
-    dC = d * C
+    C = z_ref.shape[0]
+    # float32 operands contract in float32: the MXU's default precision
+    # would round them to bfloat16
+    prec = jax.lax.Precision.HIGHEST
+    nt = (((1,), (1,)), ((), ()))                 # contract the sample axis
 
     @pl.when(t == 0)
     def _init():
         g_ref[...] = jnp.zeros_like(g_ref)
         k_ref[...] = jnp.zeros_like(k_ref)
 
-    Z = z_ref[0].astype(jnp.float32)               # (C, d, BNK)
-    Wb = w_ref[0].astype(jnp.float32).reshape(d, C)
-    eta = base_ref[0].astype(jnp.float32) + jnp.stack(
-        [jnp.dot(Wb[:, c], Z[c], preferred_element_type=jnp.float32)
-         for c in range(C)])                       # (C, BNK)
-    x = xi_ref[0].astype(jnp.float32)
-    F = ep.features(x, C)                          # (C, BNK)
-    r = ep.residual(F, eta)                        # (C, BNK)
-    kap = ep.curvature(F, eta)                     # (C, C, BNK)
+    Z = [z_ref[c].astype(jnp.float32) for c in range(C)]          # (d, bm)
+    eta = jnp.stack([
+        base_ref[c].astype(jnp.float32)
+        + jnp.dot(w_ref[c].astype(jnp.float32), Z[c], precision=prec,
+                  preferred_element_type=jnp.float32)
+        for c in range(C)])                                    # (C, 1, bm)
+    F = ep.features(xi_ref[...].astype(jnp.float32), C)        # (C, 1, bm)
+    r = ep.residual(F, eta)                                    # (C, 1, bm)
+    kap = ep.curvature(F, eta)                                 # (C, C, 1, bm)
     if weighted:
-        w = sw_ref[0].astype(jnp.float32)
-        r = r * w[None]
-        kap = kap * w[None, None]
-    # score vector, coordinate-major flat (d*C)
-    g = jnp.stack([jnp.dot(Z[c], r[c], preferred_element_type=jnp.float32)
-                   for c in range(C)], axis=1)     # (d, C)
-    g_ref[0, :] += g.reshape(dC)
-    # curvature Gram: all (C, C) cross-channel blocks, (d,c) x (f,e) flat
-    blocks = jnp.stack([
-        jnp.stack([jnp.dot(Z[c] * kap[c, e][None, :], Z[e].T,
-                           preferred_element_type=jnp.float32)
-                   for e in range(C)])
-        for c in range(C)])                        # (C, C, d, d)
-    k_ref[0, :, :] += jnp.transpose(blocks, (2, 0, 3, 1)).reshape(dC, dC)
-
-
-def lane_padded_width(d: int, C: int, lane: int) -> int:
-    """Smallest ``d' >= d`` such that ``d' * C`` is a multiple of ``lane``.
-
-    Padding the *coordinate* axis (not the flat ``d*C`` axis) keeps the
-    coordinate-major layout intact: the pad lands as trailing all-zero
-    coordinates, so ``g[:, :d*C]`` / ``K[:, :d*C, :d*C]`` slice the real
-    block back out.
-    """
-    step = lane // math.gcd(C, lane)
-    return d + ((-d) % step)
+        w = sw_ref[...].astype(jnp.float32)
+        r = r * w
+        kap = kap * w
+    for c in range(C):
+        g_ref[c] += jax.lax.dot_general(
+            r[c], Z[c], nt, precision=prec,
+            preferred_element_type=jnp.float32)                # (1, d)
+        for e in range(C):
+            k_ref[c, e] += jax.lax.dot_general(
+                Z[c] * kap[c, e], Z[e], nt, precision=prec,
+                preferred_element_type=jnp.float32)            # (d, d)
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "interpret", "tiles"))
@@ -158,54 +150,55 @@ def bucket_newton_stats(kind: str, Zb, base, xi, W, sw=None, *,
     """Pallas-fused (g, K) bucket Newton statistics; see module docstring.
 
     Same contract as :func:`bucket_newton_stats_ref`. ``interpret=None``
-    derives from the backend (compiled on TPU/GPU, interpret elsewhere —
+    derives from the backend (compiled on TPU, interpret elsewhere —
     Pallas cannot compile on CPU). ``tiles`` is an optional
-    :class:`~repro.kernels.cl.autotune.TileConfig`: ``tiles.bm`` sets the
-    sample tile (default 128) and ``tiles.lane`` zero-pads the ``d*C``
-    output axis up to a lane multiple (see :func:`lane_padded_width`).
-    All padding — sample *and* lane — is exact: padded design entries are
-    zero, so every contraction term they touch vanishes.
+    :class:`~repro.kernels.cl.autotune.TileConfig` whose ``bm`` sets the
+    sample tile (default 128). Sample padding is exact: padded design
+    entries are zero, so every contraction term they touch vanishes.
+
+    The kernel works channel-major — W in as (k, C, 1, d) rows, g out as
+    (k, C, 1, d) and K as (k, C, C, d, d) per-channel-pair blocks — and the
+    permutation to and from the coordinate-major flat layout happens here,
+    in jnp, outside the kernel.
     """
     require_epilogue(kind)
     if interpret is None:
-        interpret = jax.default_backend() not in ("tpu", "gpu")
+        interpret = target_platform() != "tpu"
     bm = BNK if tiles is None or tiles.bm is None else int(tiles.bm)
-    lane = None if tiles is None else tiles.lane
     k, C, d, n = Zb.shape
-    dC_out = d * C
-    if lane:
-        d_pad = lane_padded_width(d, C, lane) - d
-        if d_pad:
-            Zb = jnp.pad(Zb, ((0, 0), (0, 0), (0, d_pad), (0, 0)))
-            W = jnp.pad(W, ((0, 0), (0, d_pad * C)))
-            d = d + d_pad
     dC = d * C
     pad_n = (-n) % bm
     Zp = jnp.pad(Zb, ((0, 0), (0, 0), (0, 0), (0, pad_n)))
-    bp = jnp.pad(base, ((0, 0), (0, 0), (0, pad_n)))
-    xp = jnp.pad(xi, ((0, 0), (0, pad_n)))
+    bp = jnp.pad(base, ((0, 0), (0, 0), (0, pad_n)))[:, :, None, :]
+    xp = jnp.pad(xi, ((0, 0), (0, pad_n)))[:, None, :]
     weighted = sw is not None
-    swp = (jnp.pad(sw, ((0, 0), (0, pad_n))) if weighted
-           else jnp.zeros((k, n + pad_n), Zb.dtype))
+    swp = (jnp.pad(sw, ((0, 0), (0, pad_n)))[:, None, :] if weighted
+           else jnp.zeros((k, 1, n + pad_n), Zb.dtype))
+    Wc = jnp.swapaxes(W.reshape(k, d, C), 1, 2)[:, :, None, :]  # (k, C, 1, d)
 
     g, K = pl.pallas_call(
         functools.partial(_newton_kernel, kind=kind, weighted=weighted),
         grid=(k, (n + pad_n) // bm),
         in_specs=[
-            pl.BlockSpec((1, C, d, bm), lambda a, t: (a, 0, 0, t)),
-            pl.BlockSpec((1, C, bm), lambda a, t: (a, 0, t)),
-            pl.BlockSpec((1, bm), lambda a, t: (a, t)),
-            pl.BlockSpec((1, bm), lambda a, t: (a, t)),
-            pl.BlockSpec((1, dC), lambda a, t: (a, 0)),
+            pl.BlockSpec((None, C, d, bm), lambda a, t: (a, 0, 0, t)),
+            pl.BlockSpec((None, C, 1, bm), lambda a, t: (a, 0, 0, t)),
+            pl.BlockSpec((None, 1, bm), lambda a, t: (a, 0, t)),
+            pl.BlockSpec((None, 1, bm), lambda a, t: (a, 0, t)),
+            pl.BlockSpec((None, C, 1, d), lambda a, t: (a, 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, dC), lambda a, t: (a, 0)),
-            pl.BlockSpec((1, dC, dC), lambda a, t: (a, 0, 0)),
+            pl.BlockSpec((None, C, 1, d), lambda a, t: (a, 0, 0, 0)),
+            pl.BlockSpec((None, C, C, d, d), lambda a, t: (a, 0, 0, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((k, dC), jnp.float32),
-            jax.ShapeDtypeStruct((k, dC, dC), jnp.float32),
+            jax.ShapeDtypeStruct((k, C, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((k, C, C, d, d), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(Zp, bp, xp, swp, W)
-    return g[:, :dC_out], K[:, :dC_out, :dC_out]
+    )(Zp, bp, xp, swp, Wc)
+    # channel-major -> coordinate-major: g[(d,c)], K[(d,c),(f,e)]
+    g = jnp.swapaxes(g[:, :, 0, :], 1, 2).reshape(k, dC)
+    K = jnp.transpose(K, (0, 3, 1, 4, 2)).reshape(k, dC, dC)
+    return g, K

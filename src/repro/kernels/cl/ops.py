@@ -2,14 +2,19 @@
 
 Four paths, one resolver:
 
-* ``"mosaic"``    — the compiled Pallas kernel (TPU/GPU only; Pallas cannot
+* ``"mosaic"``    — the compiled Pallas kernel (TPU only; Pallas cannot
   compile on the CPU backend).
 * ``"tiled"``     — the XLA-compiled CPU twins (:mod:`.tiled`): same tiling
   idea as the Pallas kernels, compiled through plain jit. The default off
-  TPU/GPU.
+  TPU.
 * ``"ref"``       — the plain jnp reference (:mod:`.ref`).
 * ``"interpret"`` — the Pallas kernel body run in Python (validation only;
   orders of magnitude slower than everything else).
+
+The platform is the one the traced computation will run on
+(:func:`.kernel.target_platform`): a ``jax.default_device`` in scope wins
+over the default backend, so a host-CPU run on a TPU machine resolves to
+the CPU paths (the bucket solvers' jit caches key on that setting).
 
 Tile sizes come from the autotuner (:func:`.autotune.get_tiles`): cached
 tuned tiles when a search ran, deterministic shape heuristics otherwise.
@@ -28,11 +33,9 @@ means interpret mode.
 """
 from typing import Optional
 
-import jax
-
 from ...telemetry.recorder import record_kernel_trace
 from .autotune import TileConfig, get_tiles
-from .kernel import cl_score_channels, ising_cl_logits
+from .kernel import cl_score_channels, ising_cl_logits, target_platform
 from .newton import bucket_newton_stats, bucket_newton_stats_ref
 from .ref import cl_score_channels_ref, cl_score_ref, ising_cl_logits_ref
 from .score import cl_score
@@ -44,9 +47,9 @@ KERNEL_PATHS = ("mosaic", "tiled", "ref", "interpret")
 
 def default_kernel_path(backend: Optional[str] = None) -> str:
     """The path picked when callers don't force one: compiled everywhere —
-    Mosaic on TPU/GPU, the XLA-compiled tiled twins elsewhere."""
-    backend = backend or jax.default_backend()
-    return "mosaic" if backend in ("tpu", "gpu") else "tiled"
+    Mosaic on TPU, the XLA-compiled tiled twins elsewhere."""
+    backend = backend or target_platform()
+    return "mosaic" if backend == "tpu" else "tiled"
 
 
 def resolve_kernel_path(use_pallas=None, interpret: Optional[bool] = None,
@@ -58,12 +61,12 @@ def resolve_kernel_path(use_pallas=None, interpret: Optional[bool] = None,
     it compiles, ``"interpret"`` on CPU or when ``interpret=True`` asks for
     the validation mode explicitly.
     """
-    backend = backend or jax.default_backend()
+    backend = backend or target_platform()
     if use_pallas is None:
         return default_kernel_path(backend)
     if not use_pallas:
         return "ref"
-    if interpret or (interpret is None and backend not in ("tpu", "gpu")):
+    if interpret or (interpret is None and backend != "tpu"):
         return "interpret"
     return "mosaic"
 
@@ -72,8 +75,7 @@ def _tiles_for(op: str, path: str, *, n: int, p: int, C: int,
                dtype) -> Optional[TileConfig]:
     """Tuned/heuristic tiles for the executing path (None for ref)."""
     if path == "mosaic":
-        return get_tiles(op, n=n, p=p, C=C, backend=jax.default_backend(),
-                         dtype=str(dtype))
+        return get_tiles(op, n=n, p=p, C=C, backend="tpu", dtype=str(dtype))
     if path == "tiled":
         return get_tiles(op, n=n, p=p, C=C, backend="cpu", dtype=str(dtype))
     return None
@@ -152,8 +154,8 @@ def bucket_newton_stats_op(kind, Zb, base, xi, W, sw=None, *,
                            interpret: Optional[bool] = None):
     """Fused bucket Newton statistics (g, K), backend-aware.
 
-    Mosaic on TPU/GPU (lane-padded via the autotuner's tiles), the
-    XLA-compiled chunked twin on CPU, plain ref / interpret on request.
+    Mosaic on TPU (sample tile from the autotuner), the XLA-compiled
+    chunked twin on CPU, plain ref / interpret on request.
     Safe to call inside a jit trace — the path choice is a trace-time
     constant.
     """

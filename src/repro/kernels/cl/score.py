@@ -40,7 +40,7 @@ def cl_score(x, theta, mask, bias, *, kind: str = "ising",
     use :func:`cl_score_channels` / ``family_score_stats`` for those.
     Returns eta, r of shape (n, p) in x.dtype and S of shape (p, p) in
     float32. ``interpret=None`` derives from the backend (compiled on
-    TPU/GPU, interpret mode — Python-speed validation — elsewhere);
+    TPU, interpret mode — Python-speed validation — elsewhere);
     ``tiles`` is an optional autotuner :class:`TileConfig`.
     """
     ep = require_epilogue(kind)

@@ -3,7 +3,7 @@
 Pallas cannot compile on the CPU backend (interpret mode only, and
 interpret mode is a Python-speed validation tool). These entries are the
 *compiled* CPU tier the dispatch layer (:mod:`.ops`) picks by default off
-TPU/GPU: XLA-jitted mirrors of the Pallas kernels' tiling — the sample
+TPU: XLA-jitted mirrors of the Pallas kernels' tiling — the sample
 axis split into chunks, per-chunk epilogue residual/curvature, and the
 score/curvature Grams accumulated across chunks in a ``lax.scan`` — so
 the working set per step stays cache-sized the same way a VMEM tile does.

@@ -290,7 +290,7 @@ def pseudo_score(graph: Graph, theta: np.ndarray, x_pad,
     live rows.
 
     ``use_pallas=None`` takes the backend default through the dispatch
-    layer — the compiled Mosaic kernel on TPU/GPU, the XLA-compiled tiled
+    layer — the compiled Mosaic kernel on TPU, the XLA-compiled tiled
     twin elsewhere; pass ``use_pallas=True`` to force the Pallas kernel
     body, in which case ``interpret`` chooses interpret vs compiled
     execution (``None`` = compiled where the backend supports it).

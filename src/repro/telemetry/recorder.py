@@ -120,10 +120,10 @@ class _Span:
     def __init__(self, rec: "Recorder", name: str, tags: dict):
         self.rec = rec
         self.name = name
+        if not rec._stack and rec.spec.profile_dir is not None:
+            rec._profile_start()          # raises before any state changes
         rec._stack.append(name)
         _ACTIVE.append(rec)
-        if rec._outermost_profile():
-            rec._profile_start()
         self._c0 = _bucket_compiles()
         rec._emit("span_start", "/".join(rec._stack), tags=tags or None)
         self._t0 = time.perf_counter()
@@ -208,17 +208,13 @@ class Recorder:
             self._emit("point", metric, value=float(value), rnd=rnd)
 
     # ------------------------------------------------------------ profiling
-    def _outermost_profile(self) -> bool:
-        return (self.spec.profile_dir is not None
-                and len(self._stack) == 1 and not self._profiling)
-
     def _profile_start(self) -> None:
-        try:
-            import jax
-            jax.profiler.start_trace(self.spec.profile_dir)
-            self._profiling = True
-        except Exception:
-            self._profiling = False
+        """Start the ``jax.profiler`` trace for an outermost span. A trace
+        that cannot start raises: a run that asked for a profile must not
+        silently produce none."""
+        import jax
+        jax.profiler.start_trace(self.spec.profile_dir)
+        self._profiling = True
 
     def _profile_stop(self) -> None:
         if self._profiling:

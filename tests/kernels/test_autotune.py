@@ -34,19 +34,16 @@ def test_invalid_tiles_rejected():
         at.validate_tile_config(TileConfig(bn=-8), "score")
     with pytest.raises(ValueError, match="bk"):
         at.validate_tile_config(TileConfig(bk=0), "score")
-    with pytest.raises(ValueError, match="lane"):
-        at.validate_tile_config(TileConfig(lane=100), "newton")
     # Mosaic (compiled) constraints are stricter
     with pytest.raises(ValueError, match="8-aligned"):
         at.validate_tile_config(TileConfig(bm=None), "score", compiled=True)
     with pytest.raises(ValueError, match="128-multiple"):
         at.validate_tile_config(TileConfig(bm=128, bn=64), "score",
                                 compiled=True)
-    with pytest.raises(ValueError, match="lane"):
-        at.validate_tile_config(TileConfig(bm=128, lane=None), "newton",
-                                compiled=True)
+    with pytest.raises(ValueError, match="128-multiple bm"):
+        at.validate_tile_config(TileConfig(bm=64), "newton", compiled=True)
     # and valid configs pass through unchanged
-    cfg = TileConfig(bm=128, bn=128, bk=128, lane=128)
+    cfg = TileConfig(bm=128, bn=128, bk=128)
     assert at.validate_tile_config(cfg, "newton", compiled=True) is cfg
 
 
@@ -56,7 +53,7 @@ def test_tile_key_rejects_unknown_op():
 
 
 def test_tile_config_round_trips_dict():
-    cfg = TileConfig(bm=512, bn=256, bk=128, lane=128)
+    cfg = TileConfig(bm=512, bn=256, bk=128)
     assert TileConfig.from_dict(cfg.to_dict()) == cfg
     assert TileConfig.from_dict(TileConfig(bm=None).to_dict()).bm is None
 

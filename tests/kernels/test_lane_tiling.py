@@ -1,11 +1,11 @@
-"""Lane-aligned tiling invariants for the fused CL kernels.
+"""Edge-tile invariants for the fused CL kernels.
 
-The acceptance contract of the tiling tentpole: zero padding is provably
+The acceptance contract of the tiling: zero padding is provably
 invisible —
 
-* the bucket Newton kernel's tiny ``d*C`` output axis can be padded up to
-  any lane multiple without changing g or K (padded design rows are zero,
-  so every contribution vanishes term-by-term);
+* the bucket Newton kernel's sample tile need not divide n (padded design
+  columns are zero, so every contribution vanishes term-by-term), for
+  every family epilogue, weighted or not;
 * the ``(j, i, k)`` score-kernel grid handles shapes that do NOT divide
   the tile sizes (edge tiles) exactly like shapes that do.
 
@@ -23,31 +23,19 @@ import jax.numpy as jnp  # noqa: E402
 from repro.kernels.cl.autotune import TileConfig  # noqa: E402
 from repro.kernels.cl.kernel import cl_score_channels  # noqa: E402
 from repro.kernels.cl.newton import (bucket_newton_stats,  # noqa: E402
-                                     bucket_newton_stats_ref,
-                                     lane_padded_width)
+                                     bucket_newton_stats_ref)
 from repro.kernels.cl.ref import cl_score_channels_ref  # noqa: E402
 
 
-# ------------------------------------------------------- lane-pad algebra
-@given(d=st.integers(1, 40), C=st.integers(1, 6),
-       lane=st.sampled_from([8, 16, 32, 64, 128]))
-@settings(max_examples=60, deadline=None)
-def test_lane_padded_width_is_minimal_and_aligned(d, C, lane):
-    dp = lane_padded_width(d, C, lane)
-    assert dp >= d
-    assert (dp * C) % lane == 0
-    # minimal: no smaller d' >= d aligns
-    for cand in range(d, dp):
-        assert (cand * C) % lane != 0
-
-
-# ------------------------------------------- lane padding invisible (g, K)
+# ------------------------------------- sample edge tiles invisible (g, K)
 def _newton_case(kind, k, C, d, n, seed, weighted):
     ks = jax.random.split(jax.random.PRNGKey(seed), 5)
     Zb = jax.random.normal(ks[0], (k, C, d, n))
     base = 0.1 * jax.random.normal(ks[1], (k, C, n))
     if kind == "potts":
         xi = jax.random.randint(ks[2], (k, n), 0, C + 1).astype(jnp.float32)
+    elif kind == "gaussian":
+        xi = jax.random.normal(ks[2], (k, n))
     else:
         xi = jnp.sign(jax.random.normal(ks[2], (k, n)))
     W = 0.2 * jax.random.normal(ks[3], (k, d * C))
@@ -55,37 +43,41 @@ def _newton_case(kind, k, C, d, n, seed, weighted):
     return Zb, base, xi, W, sw
 
 
-@given(d=st.integers(1, 6), n=st.integers(1, 50),
-       lane=st.sampled_from([8, 16, 32]),
-       bm=st.sampled_from([8, 16, 32]),
-       weighted=st.booleans(), seed=st.integers(0, 2 ** 16))
-@settings(max_examples=12, deadline=None)
-def test_lane_padded_newton_matches_ref_potts(d, n, lane, bm, weighted,
-                                              seed):
-    """Interpret-mode bucket Newton with lane padding AND a sample tile
-    that does not divide n == the unpadded jnp reference (multi-channel)."""
-    kind, C, k = "potts", 2, 2
+def _check_newton(kind, C, k, d, n, bm, weighted, seed):
     Zb, base, xi, W, sw = _newton_case(kind, k, C, d, n, seed, weighted)
     g0, K0 = bucket_newton_stats_ref(kind, Zb, base, xi, W, sw)
     g1, K1 = bucket_newton_stats(kind, Zb, base, xi, W, sw, interpret=True,
-                                 tiles=TileConfig(bm=bm, lane=lane))
+                                 tiles=TileConfig(bm=bm))
     assert g1.shape == g0.shape and K1.shape == K0.shape
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g0), atol=2e-5)
     np.testing.assert_allclose(np.asarray(K1), np.asarray(K0), atol=2e-5)
 
 
-@given(d=st.integers(1, 8), n=st.integers(1, 60),
-       lane=st.sampled_from([8, 32, 128]), seed=st.integers(0, 2 ** 16))
+@given(d=st.integers(1, 6), n=st.integers(1, 50),
+       bm=st.sampled_from([8, 16, 32]),
+       weighted=st.booleans(), seed=st.integers(0, 2 ** 16))
 @settings(max_examples=12, deadline=None)
-def test_lane_padded_newton_matches_ref_ising(d, n, lane, seed):
-    """Single-channel fast path under lane padding."""
-    kind, C, k = "ising", 1, 3
-    Zb, base, xi, W, sw = _newton_case(kind, k, C, d, n, seed, False)
-    g0, K0 = bucket_newton_stats_ref(kind, Zb, base, xi, W)
-    g1, K1 = bucket_newton_stats(kind, Zb, base, xi, W, interpret=True,
-                                 tiles=TileConfig(bm=16, lane=lane))
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g0), atol=2e-5)
-    np.testing.assert_allclose(np.asarray(K1), np.asarray(K0), atol=2e-5)
+def test_newton_edge_tiles_match_ref_potts(d, n, bm, weighted, seed):
+    """Interpret-mode bucket Newton with a sample tile that does not
+    divide n == the jnp reference (multi-channel, channel-major blocks
+    permuted back to the coordinate-major flat layout)."""
+    _check_newton("potts", 2, 2, d, n, bm, weighted, seed)
+
+
+@given(d=st.integers(1, 8), n=st.integers(1, 60),
+       bm=st.sampled_from([8, 16, 128]), seed=st.integers(0, 2 ** 16))
+@settings(max_examples=12, deadline=None)
+def test_newton_edge_tiles_match_ref_ising(d, n, bm, seed):
+    """Single-channel path under sample edge tiles."""
+    _check_newton("ising", 1, 3, d, n, bm, False, seed)
+
+
+@given(d=st.integers(1, 8), n=st.integers(1, 60),
+       bm=st.sampled_from([8, 16, 128]), seed=st.integers(0, 2 ** 16))
+@settings(max_examples=12, deadline=None)
+def test_newton_edge_tiles_match_ref_gaussian_weighted(d, n, bm, seed):
+    """Constant-curvature epilogue with per-node sample weights."""
+    _check_newton("gaussian", 1, 3, d, n, bm, True, seed)
 
 
 # ------------------------------------------------- score-kernel edge tiles

@@ -162,6 +162,26 @@ def test_spec_round_trip_and_validation():
         TelemetrySpec(profile_dir=3.5)
 
 
+def test_profile_start_failure_raises_and_leaves_no_open_span(
+        monkeypatch, tmp_path):
+    """A span that asked for a profiler trace fails loudly when the trace
+    cannot start, and leaves neither the recorder nor the kernel-tag stack
+    holding a half-opened span."""
+    import jax
+
+    def refuse(*args, **kwargs):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    rec = Recorder(TelemetrySpec(profile_dir=str(tmp_path)))
+    depth = len(_ACTIVE)
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        with rec.span("fit"):
+            pass
+    assert rec._stack == [] and len(_ACTIVE) == depth
+    assert rec._profiling is False and rec.events == []
+
+
 def test_null_recorder_span_is_cheap():
     """100k disabled span entries must be effectively free (generous CI
     bound — the point is catching an accidental allocation/IO path on the
