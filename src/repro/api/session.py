@@ -211,22 +211,17 @@ class EstimationSession:
             n = int(Xj.shape[0])
             fits = self.fit_local(Xj, sample_weight=sample_weight,
                                   warm_start=warm_start, stats=stats)
-
-            def _combine_one(c):
-                return c.combine(
-                    self.graph, fits,
-                    include_singleton=self.plan.include_singleton,
-                    theta_fixed=self.theta_fixed, family=self.family)
-
             combined = {}
             for c in self.combiners:
-                if rec.enabled:
-                    with rec.span("combine", scheme=c.name):
-                        combined[c.name] = _combine_one(c)
-                else:
-                    combined[c.name] = _combine_one(c)
+                # the child span names the combiner on the profiler trace
+                with rec.span("combine", scheme=c.name), rec.span(c.name):
+                    combined[c.name] = c.combine(
+                        self.graph, fits,
+                        include_singleton=self.plan.include_singleton,
+                        theta_fixed=self.theta_fixed, family=self.family)
             theta = combined[self.plan.combiners[0]]
-            score = self._score_norm(theta, Xj, n)
+            with rec.span("score"):
+                score = self._score_norm(theta, Xj, n)
         c1 = bucket_compile_count()
         self.fit_calls += 1
         comm = self._one_step_comm(n)
@@ -293,7 +288,8 @@ class EstimationSession:
                 mesh=self.mesh, sample_weight=sample_weight,
                 rho0=plan.admm_rho, recorder=self.recorder, stats=stats)
             theta = res.trajectory[-1]
-            score = self._score_norm(theta, Xj, n)
+            with rec.span("score"):
+                score = self._score_norm(theta, Xj, n)
         c1 = bucket_compile_count()
         comm = plan.admm_iters * 2 * sum(len(b) for b in self.betas)
         if rec.enabled:

@@ -62,7 +62,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..kernels.cl.epilogues import get_epilogue
 from ..kernels.cl.ops import bucket_newton_stats_op
-from ..telemetry.recorder import NULL_RECORDER
+from ..telemetry.recorder import D2H_BYTES, NULL_RECORDER
 from .estimators import LocalFit
 from .families import ISING
 from .graphs import Graph
@@ -469,6 +469,7 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
 @functools.partial(jax.jit,
                    static_argnames=("include_singleton", "n_iter", "weighted",
                                     "guarded", "family", "want_influence"))
+@jax.named_scope("bucket_solve")
 def _solve_bucket(X, nodes, nbrs, mask, offsets, W0, sw,
                   include_singleton: bool, n_iter: int, weighted: bool = False,
                   guarded: bool = False, family=ISING, tol: float = 2e-6,
@@ -493,6 +494,7 @@ def _mesh_data_size(mesh) -> int:
                    static_argnames=("include_singleton", "n_iter", "weighted",
                                     "guarded", "family", "mesh",
                                     "want_influence"))
+@jax.named_scope("bucket_solve")
 def _solve_bucket_sharded(X, nodes, nbrs, mask, offsets, W0, sw,
                           include_singleton: bool, n_iter: int,
                           weighted: bool = False, guarded: bool = False,
@@ -641,10 +643,14 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
     requested combiners never declare ``"influence"`` opts out.
 
     Observability: ``recorder`` (a :mod:`repro.telemetry` recorder; the
-    allocation-free ``NULL_RECORDER`` when None) gets one ``bucket_solve``
-    span per degree bucket with Newton-iteration histograms and, on a
-    mesh, one ``engine.shard_rows`` observation per device (the bucket
-    rows that device solved, tagged ``deg_pad`` and ``device``); ``stats``
+    allocation-free ``NULL_RECORDER`` when None) gets three spans per
+    degree bucket, ``bucket_prep`` (offsets, weights, warm start),
+    ``bucket_solve`` (the solve and its fetch to the host) and
+    ``assemble`` (the bucket's ``LocalFit`` objects), one
+    ``engine.d2h_bytes`` increment per bucket fetch, Newton-iteration
+    histograms and, on a mesh, one ``engine.shard_rows`` observation per
+    device (the bucket rows that device solved, tagged ``deg_pad`` and
+    ``device``); ``stats``
     (a caller-provided dict) receives the compile-time split —
     ``stats["compile_s"]`` accumulates the wall seconds of bucket
     dispatches that triggered a compilation (the first-dispatch path) and
@@ -654,7 +660,7 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
     if family is None:
         family = ISING
     rec = NULL_RECORDER if recorder is None else recorder
-    track = stats is not None or rec.enabled
+    track = stats is not None
     C = family.block_dim
     if theta_fixed is None:
         theta_fixed = jnp.zeros(family.n_params(graph), X.dtype)
@@ -666,72 +672,69 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
     out: List[Optional[LocalFit]] = [None] * graph.p
     for b in degree_buckets(graph):
         k = len(b.nodes)
-        offsets = node_tf[jnp.asarray(b.nodes)]
-        dC = (b.deg_pad + lead) * C
-        sw = _bucket_weights(sample_weight, b.nodes, n)
-        W0 = _bucket_warm_start(warm_start, b, dC, lead, C,
-                                _solver_dtype(X.dtype))
-        weighted = sample_weight is not None
-        if sw is None:
-            sw = jnp.ones((1, 1), _solver_dtype(X.dtype))  # never read
+        with rec.span("bucket_prep", deg_pad=b.deg_pad, k=k):
+            offsets = node_tf[jnp.asarray(b.nodes)]
+            dC = (b.deg_pad + lead) * C
+            sw = _bucket_weights(sample_weight, b.nodes, n)
+            W0 = _bucket_warm_start(warm_start, b, dC, lead, C,
+                                    _solver_dtype(X.dtype))
+            weighted = sample_weight is not None
+            if sw is None:
+                sw = jnp.ones((1, 1), _solver_dtype(X.dtype))  # never read
         if track:
             c0 = bucket_compile_count()
             t0 = time.perf_counter()
-        span = (rec.span("bucket_solve", deg_pad=b.deg_pad, k=k)
-                if rec.enabled else None)
-        if span is not None:
-            span.__enter__()
-        if mesh is None:
-            W, H, J, V, S, I = _solve_bucket(
-                X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
-                jnp.asarray(b.mask), offsets, W0, sw, include_singleton,
-                n_iter, weighted, warm_start is not None, family,
-                want_influence=want_influence)
-        else:
-            shards = _mesh_data_size(mesh)
-            nodes_, nbrs_, mask_, offsets_, W0_ = _pad_bucket_rows(
-                shards, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
-                jnp.asarray(b.mask), offsets, W0)
-            sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
-            W, H, J, V, S, I = _solve_bucket_sharded(
-                X, nodes_, nbrs_, mask_, offsets_, W0_, sw_,
-                include_singleton, n_iter, weighted,
-                warm_start is not None, family, mesh,
-                want_influence=want_influence)
+        with rec.span("bucket_solve", deg_pad=b.deg_pad, k=k):
+            if mesh is None:
+                W, H, J, V, S, I = _solve_bucket(
+                    X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
+                    jnp.asarray(b.mask), offsets, W0, sw, include_singleton,
+                    n_iter, weighted, warm_start is not None, family,
+                    want_influence=want_influence)
+            else:
+                shards = _mesh_data_size(mesh)
+                nodes_, nbrs_, mask_, offsets_, W0_ = _pad_bucket_rows(
+                    shards, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
+                    jnp.asarray(b.mask), offsets, W0)
+                sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
+                W, H, J, V, S, I = _solve_bucket_sharded(
+                    X, nodes_, nbrs_, mask_, offsets_, W0_, sw_,
+                    include_singleton, n_iter, weighted,
+                    warm_start is not None, family, mesh,
+                    want_influence=want_influence)
+                if rec.enabled:
+                    # bucket rows (padding included) each device solved
+                    for s in W.addressable_shards:
+                        rec.observe("engine.shard_rows",
+                                    int(s.data.shape[0]),
+                                    deg_pad=b.deg_pad,
+                                    device=int(s.device.id))
             if rec.enabled:
-                # bucket rows (padding included) each device solved
-                for s in W.addressable_shards:
-                    rec.observe("engine.shard_rows", int(s.data.shape[0]),
-                                deg_pad=b.deg_pad, device=int(s.device.id))
-        W, H, J, V, S = (np.asarray(W)[:k], np.asarray(H)[:k],
-                         np.asarray(J)[:k], np.asarray(V)[:k],
-                         np.asarray(S)[:k])
-        if span is not None:
-            span.__exit__(None, None, None)
+                # the Newton iteration counts, fetched for telemetry alone
+                # (below, outside the span), join the bucket's byte count
+                nbytes = sum(int(a.nbytes) for a in (W, H, J, V, S, I))
+            W, H, J, V, S = (np.asarray(a)[:k] for a in (W, H, J, V, S))
         if track:
             # the np.asarray conversions above block on the device work, so
             # dt covers trace+compile+execute for a compiling dispatch
             dt = time.perf_counter() - t0
-            c1 = bucket_compile_count()
-            compiled = c1 > c0 >= 0
-            if stats is not None:
-                stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
-                if compiled:
-                    stats["compile_s"] = stats.get("compile_s", 0.0) + dt
-            if rec.enabled:
-                rec.observe("engine.newton_iters", int(np.max(np.asarray(I)[:k])),
-                            deg_pad=b.deg_pad)
-                rec.observe("engine.bucket_dispatch_s", dt,
-                            deg_pad=b.deg_pad, compiled=compiled)
-        degs = b.mask.sum(axis=1).astype(np.int64)
-        for row, i in enumerate(b.nodes):
-            i = int(i)
-            di = (lead + int(degs[row])) * C
-            out[i] = LocalFit(
-                i=i, beta=family.beta(graph, i, include_singleton),
-                theta=W[row, :di].copy(), H=H[row, :di, :di].copy(),
-                J=J[row, :di, :di].copy(), V=V[row, :di, :di].copy(),
-                s=S[row, :, :di].copy())
+            stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
+            if bucket_compile_count() > c0 >= 0:
+                stats["compile_s"] = stats.get("compile_s", 0.0) + dt
+        if rec.enabled:
+            rec.observe("engine.newton_iters", int(np.max(np.asarray(I)[:k])),
+                        deg_pad=b.deg_pad)
+            rec.inc(D2H_BYTES, nbytes, site="bucket_solve")
+        with rec.span("assemble", deg_pad=b.deg_pad, k=k):
+            degs = b.mask.sum(axis=1).astype(np.int64)
+            for row, i in enumerate(b.nodes):
+                i = int(i)
+                di = (lead + int(degs[row])) * C
+                out[i] = LocalFit(
+                    i=i, beta=family.beta(graph, i, include_singleton),
+                    theta=W[row, :di].copy(), H=H[row, :di, :di].copy(),
+                    J=J[row, :di, :di].copy(), V=V[row, :di, :di].copy(),
+                    s=S[row, :, :di].copy())
     return out  # type: ignore[return-value]
 
 
@@ -813,6 +816,7 @@ def _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho,
 @functools.partial(jax.jit,
                    static_argnames=("include_singleton", "n_iter", "weighted",
                                     "family"))
+@jax.named_scope("prox_bucket_solve")
 def _solve_bucket_prox(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho, tbar,
                        include_singleton: bool, n_iter: int,
                        weighted: bool = False, family=ISING, tol: float = 2e-6,
@@ -826,6 +830,7 @@ def _solve_bucket_prox(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho, tbar,
 @functools.partial(jax.jit,
                    static_argnames=("include_singleton", "n_iter", "weighted",
                                     "family", "mesh"))
+@jax.named_scope("prox_bucket_solve")
 def _solve_bucket_prox_sharded(X, nodes, nbrs, mask, offsets, W0, sw, lam,
                                rho, tbar, include_singleton: bool,
                                n_iter: int, weighted: bool = False,
@@ -919,14 +924,15 @@ def prox_update_batched(graph: Graph, X: jnp.ndarray,
     (bucket nodes sharded along the mesh's ``data`` axis). Returns the
     updated per-node theta vectors.
 
-    ``recorder`` / ``stats`` mirror :func:`fit_all_local_batched`: one
-    ``prox_bucket_solve`` span per bucket, and ``stats["compile_s"]`` /
+    ``recorder`` / ``stats`` mirror :func:`fit_all_local_batched`: a
+    ``bucket_prep`` and a ``prox_bucket_solve`` span and one
+    ``engine.d2h_bytes`` increment per bucket, and ``stats["compile_s"]`` /
     ``stats["dispatch_s"]`` accumulation keyed to the prox-solver caches.
     """
     if family is None:
         family = ISING
     rec = NULL_RECORDER if recorder is None else recorder
-    track = stats is not None or rec.enabled
+    track = stats is not None
     C = family.block_dim
     if theta_fixed is None:
         theta_fixed = jnp.zeros(family.n_params(graph), X.dtype)
@@ -941,74 +947,69 @@ def prox_update_batched(graph: Graph, X: jnp.ndarray,
     out: List[Optional[np.ndarray]] = [None] * graph.p
     for b in degree_buckets(graph):
         k = len(b.nodes)
-        dC = (b.deg_pad + lead) * C
-        degs = b.mask.sum(axis=1).astype(np.int64)
-        lam = np.zeros((k, dC), dtype=np.float32)
-        rho = np.zeros((k, dC), dtype=np.float32)
-        tbar = np.zeros((k, dC), dtype=np.float32)
-        for row, i in enumerate(b.nodes):
-            i = int(i)
-            di = (lead + int(degs[row])) * C
-            lam[row, :di] = np.asarray(lambdas[i])[:di]
-            rho[row, :di] = np.asarray(rhos[i])[:di]
-            if per_node_bar:
-                tbar[row, :di] = np.asarray(theta_bar[i])[:di]
-            else:
-                beta = np.asarray(family.beta(graph, i, include_singleton))
-                tbar[row, :di] = theta_bar[beta][:di]
-        # warm-start at the previous iterate where given; nodes without one
-        # (thetas0 absent or a None entry) start at their consensus view
-        W0 = np.array(tbar, copy=True)
-        if thetas0 is not None:
+        with rec.span("bucket_prep", deg_pad=b.deg_pad, k=k):
+            dC = (b.deg_pad + lead) * C
+            degs = b.mask.sum(axis=1).astype(np.int64)
+            lam = np.zeros((k, dC), dtype=np.float32)
+            rho = np.zeros((k, dC), dtype=np.float32)
+            tbar = np.zeros((k, dC), dtype=np.float32)
             for row, i in enumerate(b.nodes):
-                t0 = thetas0[int(i)]
-                if t0 is not None:
-                    di = (lead + int(degs[row])) * C
-                    W0[row, :di] = np.asarray(t0, dtype=np.float32)[:di]
-        W0 = jnp.asarray(W0, dtype=_solver_dtype(X.dtype))
-        sw = _bucket_weights(sample_weight, b.nodes, n)
-        weighted = sample_weight is not None
-        if sw is None:
-            sw = jnp.ones((1, 1), _solver_dtype(X.dtype))
-        offsets = node_tf[jnp.asarray(b.nodes)]
+                i = int(i)
+                di = (lead + int(degs[row])) * C
+                lam[row, :di] = np.asarray(lambdas[i])[:di]
+                rho[row, :di] = np.asarray(rhos[i])[:di]
+                if per_node_bar:
+                    tbar[row, :di] = np.asarray(theta_bar[i])[:di]
+                else:
+                    beta = np.asarray(family.beta(graph, i,
+                                                  include_singleton))
+                    tbar[row, :di] = theta_bar[beta][:di]
+            # warm-start at the previous iterate where given; nodes without
+            # one (thetas0 absent or a None entry) start at their consensus
+            # view
+            W0 = np.array(tbar, copy=True)
+            if thetas0 is not None:
+                for row, i in enumerate(b.nodes):
+                    t0 = thetas0[int(i)]
+                    if t0 is not None:
+                        di = (lead + int(degs[row])) * C
+                        W0[row, :di] = np.asarray(t0, dtype=np.float32)[:di]
+            W0 = jnp.asarray(W0, dtype=_solver_dtype(X.dtype))
+            sw = _bucket_weights(sample_weight, b.nodes, n)
+            weighted = sample_weight is not None
+            if sw is None:
+                sw = jnp.ones((1, 1), _solver_dtype(X.dtype))
+            offsets = node_tf[jnp.asarray(b.nodes)]
         if track:
             c0 = prox_compile_count()
             t0 = time.perf_counter()
-        span = (rec.span("prox_bucket_solve", deg_pad=b.deg_pad, k=k)
-                if rec.enabled else None)
-        if span is not None:
-            span.__enter__()
-        if mesh is None:
-            W = _solve_bucket_prox(
-                X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
-                jnp.asarray(b.mask), offsets, W0, sw,
-                jnp.asarray(lam), jnp.asarray(rho), jnp.asarray(tbar),
-                include_singleton, n_iter, weighted, family)
-        else:
-            shards = _mesh_data_size(mesh)
-            nodes_, nbrs_, mask_, offsets_, W0_, lam_, rho_, tbar_ = \
-                _pad_bucket_rows(shards, jnp.asarray(b.nodes),
-                                 jnp.asarray(b.nbrs), jnp.asarray(b.mask),
-                                 offsets, W0, jnp.asarray(lam),
-                                 jnp.asarray(rho), jnp.asarray(tbar))
-            sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
-            W = _solve_bucket_prox_sharded(
-                X, nodes_, nbrs_, mask_, offsets_, W0_, sw_, lam_, rho_,
-                tbar_, include_singleton, n_iter, weighted, family, mesh)
-        W = np.asarray(W)[:len(b.nodes)]
-        if span is not None:
-            span.__exit__(None, None, None)
+        with rec.span("prox_bucket_solve", deg_pad=b.deg_pad, k=k):
+            if mesh is None:
+                W = _solve_bucket_prox(
+                    X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
+                    jnp.asarray(b.mask), offsets, W0, sw,
+                    jnp.asarray(lam), jnp.asarray(rho), jnp.asarray(tbar),
+                    include_singleton, n_iter, weighted, family)
+            else:
+                shards = _mesh_data_size(mesh)
+                nodes_, nbrs_, mask_, offsets_, W0_, lam_, rho_, tbar_ = \
+                    _pad_bucket_rows(shards, jnp.asarray(b.nodes),
+                                     jnp.asarray(b.nbrs),
+                                     jnp.asarray(b.mask), offsets, W0,
+                                     jnp.asarray(lam), jnp.asarray(rho),
+                                     jnp.asarray(tbar))
+                sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
+                W = _solve_bucket_prox_sharded(
+                    X, nodes_, nbrs_, mask_, offsets_, W0_, sw_, lam_, rho_,
+                    tbar_, include_singleton, n_iter, weighted, family, mesh)
+            if rec.enabled:
+                rec.inc(D2H_BYTES, int(W.nbytes), site="prox_bucket_solve")
+            W = np.asarray(W)[:k]
         if track:
             dt = time.perf_counter() - t0
-            c1 = prox_compile_count()
-            compiled = c1 > c0 >= 0
-            if stats is not None:
-                stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
-                if compiled:
-                    stats["compile_s"] = stats.get("compile_s", 0.0) + dt
-            if rec.enabled:
-                rec.observe("engine.prox_dispatch_s", dt,
-                            deg_pad=b.deg_pad, compiled=compiled)
+            stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
+            if prox_compile_count() > c0 >= 0:
+                stats["compile_s"] = stats.get("compile_s", 0.0) + dt
         for row, i in enumerate(b.nodes):
             di = (lead + int(degs[row])) * C
             out[int(i)] = W[row, :di].copy()

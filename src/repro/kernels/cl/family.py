@@ -14,6 +14,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from ...telemetry.recorder import record_d2h
 from .kernel import cl_score_channels, target_platform
 
 
@@ -85,6 +86,7 @@ def fused_pseudo_score(family, graph, theta, x_pad, n_seen: int, *,
                                    interpret=interpret,
                                    use_pallas=use_pallas)
     n_seen = int(n_seen)
+    record_d2h("score", S, r)
     S = np.asarray(S, dtype=np.float64) * (x_pad.shape[0] / max(n_seen, 1))
     r = np.asarray(r, dtype=np.float64)[:, :n_seen, :]     # live rows only
     g = np.zeros(family.n_params(graph))

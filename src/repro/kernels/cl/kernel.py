@@ -131,6 +131,7 @@ def cl_logits(F, theta, mask, bias, *, interpret: Optional[bool] = None,
     grid = (np_ // bm, pp // bn, pp // bk)
     out = pl.pallas_call(
         _logits_kernel,
+        name="cl_logits",
         grid=grid,
         in_specs=[
             pl.BlockSpec((C, bm, bk), lambda i, j, k: (0, i, k)),
@@ -252,6 +253,7 @@ def _score_kernel(f_ref, theta_ref, mask_ref, bias_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "kind", "tiles"))
+@jax.named_scope("score")
 def cl_score_channels(F, theta, mask, bias, *, kind: str,
                       interpret: Optional[bool] = None, tiles=None):
     """(eta, r, S) = fused channelized score statistics; see module docstring.
@@ -289,6 +291,7 @@ def cl_score_channels(F, theta, mask, bias, *, kind: str,
         eta, r, s = pl.pallas_call(
             functools.partial(_score_kernel_c1, n=n, kind=kind, bn=bn,
                               bk=bk),
+            name="cl_score_channels",
             grid=grid,
             in_specs=[
                 pl.BlockSpec((bm, bk), lambda j, i, k: (i, k)),
@@ -316,6 +319,7 @@ def cl_score_channels(F, theta, mask, bias, *, kind: str,
                 s[None, None, :p, :p])
     eta, r, s = pl.pallas_call(
         functools.partial(_score_kernel, n=n, kind=kind, bn=bn, bk=bk),
+        name="cl_score_channels",
         grid=grid,
         in_specs=[
             pl.BlockSpec((C, bm, bk), lambda j, i, k: (0, i, k)),

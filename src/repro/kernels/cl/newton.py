@@ -178,6 +178,7 @@ def bucket_newton_stats(kind: str, Zb, base, xi, W, sw=None, *,
 
     g, K = pl.pallas_call(
         functools.partial(_newton_kernel, kind=kind, weighted=weighted),
+        name="bucket_newton_stats",
         grid=(k, (n + pad_n) // bm),
         in_specs=[
             pl.BlockSpec((None, C, d, bm), lambda a, t: (a, 0, 0, t)),

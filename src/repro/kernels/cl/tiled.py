@@ -45,6 +45,7 @@ __all__ = ["cl_score_channels_tiled", "bucket_newton_stats_tiled"]
 
 
 @functools.partial(jax.jit, static_argnames=("kind", "chunk"))
+@jax.named_scope("score")
 def cl_score_channels_tiled(F, theta, mask, bias, *, kind: str,
                             chunk=None):
     """(eta, r, S) fused channelized score statistics, XLA-compiled.

@@ -15,11 +15,22 @@ Two implementations of one implicit protocol:
   samples. Every event lands in the in-memory list and, when the spec
   names a ``jsonl`` path, in the append-only JSONL sink.
 
+Each live span is also a ``jax.profiler.TraceAnnotation`` named by its
+path, so a profiler trace taken around any call (``jax.profiler.trace``)
+shows the program's layers on the device trace's clock. The annotation
+is the innermost interval of its span (opened after the span's start
+clock, closed before its end clock) and carries the span's tags plus
+``call``, the id of the outermost open span, which groups one verb's
+spans together. A span's id is the ``seq`` of its ``span_start`` event;
+``span_start``/``span_end`` events carry it as ``id`` with the enclosing
+span's id as ``parent`` (None at the top).
+
 While any real span is open the recorder is also *active* for trace-time
 kernel tags: :func:`record_kernel_trace`, called from the kernel dispatch
 layer (``repro.kernels.cl.ops``) during jit tracing, lands kernel-kind and
-shape events on the innermost active recorder. With no active recorder the
-hook is a single falsy list check.
+shape events on the innermost active recorder; :func:`record_d2h` counts a
+device-to-host fetch made by code that holds no recorder. With no active
+recorder either hook is a single falsy list check.
 """
 from __future__ import annotations
 
@@ -28,12 +39,17 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
+from jax import profiler as _profiler
 
 from .sinks import JsonlSink
 from .spec import TelemetrySpec
 
 __all__ = ["NullRecorder", "NULL_RECORDER", "Recorder", "TelemetrySnapshot",
-           "make_recorder", "record_kernel_trace"]
+           "make_recorder", "record_kernel_trace", "record_d2h", "D2H_BYTES"]
+
+#: counter of device-to-host bytes on the estimate path: one increment per
+#: fetch group (one host round trip), tagged with the ``site`` that fetched
+D2H_BYTES = "engine.d2h_bytes"
 
 
 class _NullSpan:
@@ -100,6 +116,14 @@ def record_kernel_trace(name: str, **tags) -> None:
         _ACTIVE[-1].event(name, **tags)
 
 
+def record_d2h(site: str, *arrays) -> None:
+    """Count one device-to-host fetch of ``arrays`` (their ``nbytes``) on
+    the innermost active recorder; one list check with telemetry off."""
+    if _ACTIVE:
+        _ACTIVE[-1].inc(D2H_BYTES, sum(int(a.nbytes) for a in arrays),
+                        site=site)
+
+
 def _bucket_compiles() -> int:
     # late import: core.batched itself imports this module for NULL_RECORDER
     try:
@@ -113,36 +137,44 @@ def _bucket_compiles() -> int:
 
 
 class _Span:
-    """One open span; records start/end events and restores the stack."""
+    """One open span; records start/end events, holds its profiler
+    annotation, and restores the stack."""
 
-    __slots__ = ("rec", "name", "_t0", "_c0")
+    __slots__ = ("rec", "name", "id", "parent", "_t0", "_c0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, tags: dict):
         self.rec = rec
         self.name = name
-        if not rec._stack and rec.spec.profile_dir is not None:
-            rec._profile_start()          # raises before any state changes
+        self.id = rec._seq
+        self.parent = rec._ids[-1] if rec._ids else None
+        call = rec._ids[0] if rec._ids else self.id
         rec._stack.append(name)
+        rec._ids.append(self.id)
         _ACTIVE.append(rec)
         self._c0 = _bucket_compiles()
-        rec._emit("span_start", "/".join(rec._stack), tags=tags or None)
+        path = "/".join(rec._stack)
+        rec._emit("span_start", path, tags=tags or None,
+                  span=(self.id, self.parent))
         self._t0 = time.perf_counter()
+        self._ann = _profiler.TraceAnnotation(path, **tags, call=call)
+        self._ann.__enter__()
 
     def __enter__(self):
         return self
 
     def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
         dur = time.perf_counter() - self._t0
         c1 = _bucket_compiles()
         rec = self.rec
         path = "/".join(rec._stack)
         rec._emit("span_end", path, value=dur,
                   new_compiles=(c1 - self._c0
-                                if c1 >= 0 and self._c0 >= 0 else 0))
+                                if c1 >= 0 and self._c0 >= 0 else 0),
+                  span=(self.id, self.parent))
         rec._stack.pop()
+        rec._ids.pop()
         _ACTIVE.pop()
-        if not rec._stack:
-            rec._profile_stop()
         return False
 
 
@@ -157,15 +189,17 @@ class Recorder:
         self._seq = 0
         self._t0 = time.perf_counter()
         self._stack: List[str] = []
+        self._ids: List[int] = []
         self._sink = (JsonlSink(self.spec.jsonl)
                       if self.spec.jsonl else None)
-        self._profiling = False
 
     # ------------------------------------------------------------ emission
     def _emit(self, kind: str, name: str, value=None, tags=None,
-              rnd=None, new_compiles=None) -> None:
+              rnd=None, new_compiles=None, span=None) -> None:
         ev = {"seq": self._seq, "t": time.perf_counter() - self._t0,
               "kind": kind, "name": name}
+        if span is not None:
+            ev["id"], ev["parent"] = span
         if value is not None:
             ev["value"] = value
         if rnd is not None:
@@ -181,8 +215,9 @@ class Recorder:
 
     # ------------------------------------------------------------- recording
     def span(self, name: str, **tags) -> _Span:
-        """Open a hierarchical span (a context manager); on exit records
-        wall seconds and the bucket-solver compile-count delta."""
+        """Open a hierarchical span (a context manager) and its profiler
+        annotation; on exit records wall seconds and the bucket-solver
+        compile-count delta."""
         if not self.spec.spans:
             return _NULL_SPAN
         return _Span(self, name, tags)
@@ -206,24 +241,6 @@ class Recorder:
         """One any-time timeline sample: metric value at stream round."""
         if self.spec.metrics:
             self._emit("point", metric, value=float(value), rnd=rnd)
-
-    # ------------------------------------------------------------ profiling
-    def _profile_start(self) -> None:
-        """Start the ``jax.profiler`` trace for an outermost span. A trace
-        that cannot start raises: a run that asked for a profile must not
-        silently produce none."""
-        import jax
-        jax.profiler.start_trace(self.spec.profile_dir)
-        self._profiling = True
-
-    def _profile_stop(self) -> None:
-        if self._profiling:
-            try:
-                import jax
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-            self._profiling = False
 
     # ----------------------------------------------------------- reading out
     def mark(self) -> int:

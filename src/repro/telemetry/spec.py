@@ -24,32 +24,30 @@ class TelemetrySpec:
     jsonl — path of an append-only JSONL event log (None = in-memory
         only). Replaying the log reconstructs the exact comm accounting
         (see :mod:`repro.telemetry.replay`).
-    profile_dir — when set, activate a ``jax.profiler`` trace around the
-        outermost span of each instrumented verb (compiled regions show up
-        in the profile); silently skipped if the profiler is unavailable.
+
+    Every live span is also a ``jax.profiler`` annotation: wrap any call
+    in ``jax.profiler.trace(dir)`` to see the spans on the device trace.
     """
 
     spans: bool = True
     metrics: bool = True
     jsonl: Optional[str] = None
-    profile_dir: Optional[str] = None
 
     def __post_init__(self):
-        for field in ("jsonl", "profile_dir"):
-            v = getattr(self, field)
-            if v is not None and not isinstance(v, str):
-                raise TypeError(f"TelemetrySpec.{field} must be a path "
-                                f"string or None, got {type(v).__name__}")
+        if self.jsonl is not None and not isinstance(self.jsonl, str):
+            raise TypeError(f"TelemetrySpec.jsonl must be a path string or "
+                            f"None, got {type(self.jsonl).__name__}")
 
     # ------------------------------------------------------- serialization
     def to_dict(self) -> dict:
         """Plain-JSON form; exact inverse of :meth:`from_dict`."""
         return {"spans": self.spans, "metrics": self.metrics,
-                "jsonl": self.jsonl, "profile_dir": self.profile_dir}
+                "jsonl": self.jsonl}
 
     @classmethod
     def from_dict(cls, d: dict) -> "TelemetrySpec":
+        """Inverse of :meth:`to_dict`; keys it does not know (such as the
+        retired ``profile_dir``) are ignored."""
         return cls(spans=bool(d.get("spans", True)),
                    metrics=bool(d.get("metrics", True)),
-                   jsonl=d.get("jsonl"),
-                   profile_dir=d.get("profile_dir"))
+                   jsonl=d.get("jsonl"))

@@ -146,7 +146,7 @@ def test_make_recorder_dispatch():
     from_spec = make_recorder(TelemetrySpec())
     assert isinstance(from_spec, Recorder)
     from_dict = make_recorder({"spans": False, "metrics": True,
-                               "jsonl": None, "profile_dir": None})
+                               "jsonl": None})
     assert isinstance(from_dict, Recorder)
     assert from_dict.spec.spans is False
     with pytest.raises(TypeError, match="TelemetrySpec"):
@@ -158,28 +158,9 @@ def test_spec_round_trip_and_validation():
     assert TelemetrySpec.from_dict(spec.to_dict()) == spec
     with pytest.raises(TypeError):
         TelemetrySpec(jsonl=7)
-    with pytest.raises(TypeError):
-        TelemetrySpec(profile_dir=3.5)
-
-
-def test_profile_start_failure_raises_and_leaves_no_open_span(
-        monkeypatch, tmp_path):
-    """A span that asked for a profiler trace fails loudly when the trace
-    cannot start, and leaves neither the recorder nor the kernel-tag stack
-    holding a half-opened span."""
-    import jax
-
-    def refuse(*args, **kwargs):
-        raise RuntimeError("profiler unavailable")
-
-    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
-    rec = Recorder(TelemetrySpec(profile_dir=str(tmp_path)))
-    depth = len(_ACTIVE)
-    with pytest.raises(RuntimeError, match="profiler unavailable"):
-        with rec.span("fit"):
-            pass
-    assert rec._stack == [] and len(_ACTIVE) == depth
-    assert rec._profiling is False and rec.events == []
+    # a dict written before profile_dir was retired still loads
+    assert TelemetrySpec.from_dict(
+        dict(spec.to_dict(), profile_dir="/tmp/prof")) == spec
 
 
 def test_null_recorder_span_is_cheap():
