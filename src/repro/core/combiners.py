@@ -51,10 +51,12 @@ combined value can be driven arbitrarily far. The classical linear schemes
 all have breakdown 0 (one lying owner moves the mean arbitrarily); the
 voting/robust schemes trade statistical efficiency for a positive one.
 
-The grouped vectorized driver (pad per-node fits into dense float64 stacks,
-group parameters by owner count, batch every group's weighting) is the
-engine previously inlined in ``consensus.combine``; its numerics are pinned
-to 1e-10 by the golden fixtures, so strategies only supply *weights*.
+The grouped vectorized driver (pad per-node estimates and variances into
+dense (p, dmax) float64 stacks, group parameters by owner count, gather only
+the influence columns those groups read rather than padding them, batch
+every group's weighting) is the engine previously inlined in
+``consensus.combine``; its numerics are pinned to 1e-10 by the golden
+fixtures, so strategies only supply *weights*.
 """
 from __future__ import annotations
 
@@ -153,7 +155,7 @@ class Combiner:
             d = len(f.theta)
             theta_mat[f.i, :d] = f.theta
             vdiag_mat[f.i, :d] = np.diag(f.V)
-        s_pad = None
+        fit_of = None
         if "influence" in self.needs:
             n = fits[0].s.shape[0]
             if n == 0:
@@ -162,9 +164,7 @@ class Combiner:
                     f"columns, but the local fits were computed without "
                     f"them (want_influence=False / a plan whose combiners "
                     f"did not request 'influence')")
-            s_pad = np.zeros((graph.p, n, dmax), dtype=np.float64)
-            for f in fits:
-                s_pad[f.i, :, :len(f.theta)] = f.s
+            fit_of = {f.i: f for f in fits}
 
         owners = param_owners(graph, include_singleton, family)
         for k, (aidx, node, pos) in _owner_groups(owners).items():
@@ -187,7 +187,8 @@ class Combiner:
                 continue
 
             diag = np.where(bad, np.inf, diag)
-            cols = s_pad[node, :, pos] if s_pad is not None else None
+            cols = (_influence_columns(fit_of, node, pos, n)
+                    if fit_of is not None else None)
             w = self.group_weights(est, diag, bad, cols)
             w = np.where(bad, 0.0, w)
             wsum = np.where(all_bad, 1.0, w.sum(axis=1))
@@ -213,6 +214,30 @@ def _owner_groups(owners: Dict[int, List[Tuple[int, int]]]):
                        dtype=np.int64)
         out[k] = (aidx, node, pos)
     return out
+
+
+def _influence_columns(fit_of: dict, node: np.ndarray, pos: np.ndarray,
+                       n: int) -> np.ndarray:
+    """(P, k, n) float64 influence columns ``s[:, pos]`` of the owners
+    ``(node, pos)``, from ``fit_of`` (node -> its ``LocalFit``).
+
+    Only the columns the group reads are copied, one fancy index per owner
+    node; a node with no fit, or a position past its ``theta``, reads as a
+    zero column. The result is C-contiguous: a Gram ``cols @ cols.T`` over
+    another memory layout can differ in the last bits.
+    """
+    node_f, pos_f = node.ravel(), pos.ravel()
+    cols = np.zeros((node_f.size, n), dtype=np.float64)
+    order = np.argsort(node_f, kind="stable")
+    cuts = np.flatnonzero(np.diff(node_f[order])) + 1
+    for rows in np.split(order, cuts):
+        f = fit_of.get(int(node_f[rows[0]]))
+        if f is None:
+            continue
+        p = pos_f[rows]
+        ok = p < len(f.theta)
+        cols[rows[ok]] = f.s[:, p[ok]].T
+    return cols.reshape(node.shape + (n,))
 
 
 # ------------------------------------------------------------- strategies
