@@ -27,7 +27,12 @@ embarrassing parallelism structurally:
   per-node systems — and the custom-call lowering that dominates compile
   time;
 * iteration stops early (``while_loop``) once every node's damped Newton
-  step is below tolerance, instead of always burning the full budget.
+  step is below tolerance, instead of always burning the full budget; a
+  large bucket finishes its last few unconverged rows on their own;
+* the sandwich variance is formed on the host in float64 from compensated
+  device sums, since an ill-conditioned node (a lattice site its
+  neighbours nearly determine) magnifies float32 rounding in H by its
+  condition number.
 
 Padding is exact: padded design columns are zero, so their gradient entries
 vanish and the Hessian is block-diagonal with a ``-1`` placeholder on padded
@@ -53,7 +58,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from typing import Dict, List, Optional, Sequence
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +118,13 @@ def _float32_contractions(fn):
     return traced
 
 
+# A bucket of more than _SPLIT_ROWS rows finishes its last _STRAGGLER_ROWS
+# unconverged rows on their own (see _solve_bucket_impl); a smaller bucket
+# iterates whole.
+_SPLIT_ROWS = 512
+_STRAGGLER_ROWS = 64
+
+
 def _pad_degree(deg: int) -> int:
     """Bucket width for a node of degree ``deg``: next power of 4 (min 1).
 
@@ -137,27 +149,24 @@ class DegreeBucket:
 
 @functools.lru_cache(maxsize=64)
 def _degree_buckets_cached(graph: Graph):
-    by_pad: Dict[int, List[int]] = {}
-    nbrs_of: Dict[int, List[int]] = {}
-    for i in range(graph.p):
-        ks = graph.incident_edges(i)
-        others = [graph.edges[k][0] if graph.edges[k][1] == i
-                  else graph.edges[k][1] for k in ks]
-        nbrs_of[i] = others
-        by_pad.setdefault(_pad_degree(len(others)), []).append(i)
-
+    # read the graph's incidence index: row r of a bucket lists node
+    # nodes[r]'s neighbours in incident-edge order, then zero padding
+    ptr, _, other = graph.incidence()
+    degs = np.diff(ptr)
+    uniq, inv = np.unique(degs, return_inverse=True)
+    pads = np.asarray([_pad_degree(int(d)) for d in uniq],
+                      dtype=np.int64)[inv.reshape(-1)]
     buckets = []
-    for deg_pad in sorted(by_pad):
-        nodes = np.asarray(sorted(by_pad[deg_pad]), dtype=np.int32)
-        k = len(nodes)
-        nbrs = np.zeros((k, deg_pad), dtype=np.int32)
-        mask = np.zeros((k, deg_pad), dtype=np.float32)
-        for row, i in enumerate(nodes):
-            d = len(nbrs_of[i])
-            nbrs[row, :d] = nbrs_of[i]
-            mask[row, :d] = 1.0
+    for deg_pad in np.unique(pads):
+        deg_pad = int(deg_pad)
+        nodes = np.flatnonzero(pads == deg_pad).astype(np.int32)
+        cols = np.arange(deg_pad)[None, :]
+        real = cols < degs[nodes][:, None]
+        nbrs = np.zeros((len(nodes), deg_pad), dtype=np.int32)
+        nbrs[real] = other[(ptr[nodes][:, None] + cols)[real]]
         buckets.append(DegreeBucket(deg_pad=deg_pad, nodes=nodes,
-                                    nbrs=nbrs, mask=mask))
+                                    nbrs=nbrs,
+                                    mask=real.astype(np.float32)))
     return tuple(buckets)
 
 
@@ -192,6 +201,59 @@ def _gauss_jordan_solve(A: jnp.ndarray, B: jnp.ndarray) -> jnp.ndarray:
 
     M = jax.lax.fori_loop(0, d, body, M)
     return M[:, :, d:]
+
+
+def _gram_hilo(Z: jnp.ndarray, w: jnp.ndarray, block: int = 128):
+    """(k, 2, d, d) compensated sums sum_n w[k, n] Z[k, a, n] Z[k, b, n].
+
+    Partial sums over blocks of ``block`` samples, then a pairwise tree of
+    error-free float32 additions (two-sum) over the blocks: [:, 0] is the
+    rounded sum and [:, 1] what rounding left out, so hi + lo in float64
+    holds the sum to about an ulp of float32, where one float32 contraction
+    over 8192 samples misses by up to 9e-6 relative on the CPU.
+    """
+    k, d, n = Z.shape
+    pad = (-n) % block
+    Z = jnp.pad(Z, ((0, 0), (0, 0), (0, pad)))
+    w = jnp.pad(w, ((0, 0), (0, pad)))
+    nb = (n + pad) // block
+    Zr = Z.reshape(k, d, nb, block)
+    hi = jnp.einsum("kdbn,kebn->bkde", Zr * w.reshape(k, 1, nb, block), Zr)
+    lo = jnp.zeros_like(hi)
+    while hi.shape[0] > 1:
+        if hi.shape[0] % 2:
+            hi = jnp.concatenate([hi, jnp.zeros_like(hi[:1])])
+            lo = jnp.concatenate([lo, jnp.zeros_like(lo[:1])])
+        h = hi.shape[0] // 2
+        a, b = hi[:h], hi[h:]
+        s = a + b
+        bb = s - a
+        err = (a - (s - bb)) + (b - bb)
+        hi, lo = s, lo[:h] + lo[h:] + err
+    return jnp.stack([hi[0], lo[0]], axis=1)
+
+
+def _sandwich_host(H2, J2, denom, cmask, C: int):
+    """Float64 sandwich V = H^-1 J H^-1 of a fetched bucket.
+
+    H2, J2: (k, 2, dC, dC) compensated sums (:func:`_gram_hilo`); denom:
+    (k,) sample counts; cmask: (k, d) 1 on real coordinates. An
+    ill-conditioned fit (a lattice site whose neighbours nearly determine
+    it) multiplies float32 rounding in H by its condition number, so the
+    inverse is formed here in float64. Returns float64 (H, J, V) averages;
+    padded coordinates are zero in H and J, and take a unit placeholder
+    diagonal in the inverse.
+    """
+    H = (H2[:, 0].astype(np.float64) + H2[:, 1]) / denom[:, None, None]
+    J = (J2[:, 0].astype(np.float64) + J2[:, 1]) / denom[:, None, None]
+    pad = 1.0 - np.repeat(np.asarray(cmask, np.float64), C, axis=1)
+    Hreg = H + (1e-9 + pad)[:, :, None] * np.eye(H.shape[-1])
+    try:
+        Hinv = np.linalg.inv(Hreg)
+    except np.linalg.LinAlgError:
+        Hinv = np.linalg.pinv(Hreg)
+    V = Hinv @ J @ np.swapaxes(Hinv, 1, 2)
+    return H, J, V
 
 
 def _solver_dtype(dtype):
@@ -247,7 +309,7 @@ def _flat_coord_mask(cmask: jnp.ndarray, C: int) -> jnp.ndarray:
     return jnp.broadcast_to(cmask[:, :, None], (k, d, C)).reshape(k, d * C)
 
 
-def _channel_ops(family, Zb, base, xi, sw, weighted, denom):
+def _channel_ops(family, Zb, base, xi, sw, weighted, denom, terms=None):
     """Channelized-GLM contraction closures shared by the plain and proximal
     bucket solvers, all in the flat coordinate-major (k, d*C) layout.
 
@@ -261,7 +323,9 @@ def _channel_ops(family, Zb, base, xi, sw, weighted, denom):
     flat W, the flat gradient vector from a channel score, the (k, dC, dC)
     curvature matrix from a channel curvature, the (c, k) per-node average
     loglik of a candidate stack, the (k, dC, n) per-sample score matrix,
-    and the fused Newton statistics ``W -> (g_raw, K_raw)``.
+    and the fused Newton statistics ``W -> (g_raw, K_raw)``. ``terms``
+    replaces the family's score and curvature hooks in ``score_curvature``
+    (the sandwich passes ``family.sandwich_terms``).
 
     ``newton_stats`` is the per-iteration hot path: for families with a
     registered fused-kernel epilogue (``family.kernel_kind``) it goes
@@ -283,8 +347,11 @@ def _channel_ops(family, Zb, base, xi, sw, weighted, denom):
 
     def score_curvature(W):
         eta = eta_of(W)
-        r = family.dl_deta(eta, xi)                          # (k, C, n)
-        kap = family.curvature(eta, xi)                      # (k, C, C, n)
+        if terms is None:
+            r = family.dl_deta(eta, xi)                      # (k, C, n)
+            kap = family.curvature(eta, xi)                  # (k, C, C, n)
+        else:
+            r, kap = terms(eta, xi)
         if weighted:
             r = r * sw[:, None, :]
             kap = kap * sw[:, None, None, :]
@@ -362,21 +429,26 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     past it only bounces around the optimum, which is all the seed's fixed
     40-iteration schedule does after convergence.
 
-    Returns (W, H, J, V, S, I) with leading bucket dimension k and flat
-    parameter dimension d*C (coordinate-major blocks); padded coordinates
-    are exactly zero in W and carry a ``-1`` placeholder diagonal in the
+    Returns (W, H, J, S, I, D) with leading bucket dimension k and flat
+    parameter dimension d*C (coordinate-major blocks): W the estimates, H
+    and J the (k, 2, dC, dC) compensated curvature and score-outer-product
+    sums (:func:`_gram_hilo`) from which the host forms the float64
+    sandwich (:func:`_sandwich_host`), S the influence stack and D the
+    (k,) sample counts the sums average over. Padded coordinates are
+    exactly zero in W and carry a ``-1`` placeholder diagonal in the
     Newton system. ``I`` is the (k,) Newton-iteration count the damped
-    solve actually used (bucket-wide — the while_loop stops when every
-    node's step converged — broadcast per node so it shards like the other
+    solve actually used (bucket-wide — the loop stops when every node's
+    step converged — broadcast per node so it shards like the other
     outputs). A node whose weights sum to zero (nothing observed yet)
     stays at W0 untouched by data: its gradient vanishes and the guarded
     denominator keeps it finite.
 
     ``axis_name`` names the mesh axis when this runs as one shard of a
     bucket: the convergence test then takes the step's max over all
-    shards, so every shard runs the iteration count the whole bucket
-    would run on one device and the result does not depend on the
-    device count.
+    shards, so every shard runs the bucket-wide iteration count. A shard
+    of more than ``_SPLIT_ROWS`` rows finishes its own slowest rows alone,
+    so results agree across device counts to Newton tolerance, and exactly
+    on one device.
     """
     n = X.shape[0]
     Zb, xi, base, cmask = _bucket_design(family, X, nodes, nbrs, mask,
@@ -396,74 +468,109 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     else:
         denom = jnp.full((k,), float(n), cdtype)
 
-    score_curvature, grad_vec, curvature_matrix, objective, score_matrix, \
-        newton_stats = _channel_ops(family, Zb, base, xi, sw, weighted, denom)
+    def iterate(Zb, base, xi, sw, denom, pad_diag, W, it, delta, stop):
+        """Damped Newton on these rows from W: while under the budget, some
+        row's step is above ``tol`` and more than ``stop`` rows are."""
+        *_, objective, _, newton_stats = _channel_ops(
+            family, Zb, base, xi, sw, weighted, denom)
 
-    def cond(carry):
-        _, it, delta = carry
-        return (it < n_iter) & (delta > tol)
+        def cond(carry):
+            _, it, delta, live, _ = carry
+            return (it < n_iter) & (delta > tol) & (live > stop)
 
-    def newton_step(carry):
-        W, it, _ = carry
-        g_raw, K_raw = newton_stats(W)           # fused score + Gram
-        g = g_raw / denom[:, None]
-        H = -K_raw / denom[:, None, None] \
-            - ridge * eye[None, :, :] - pad_diag
-        dirn = _gauss_jordan_solve(H, g[..., None])[..., 0]  # (k, dC)
-        # an untrusted direction: non-finite (curvature underflow at a
-        # saturated point makes the solve blow up) or clipped (outside
-        # Newton's trust region). NaN directions are zeroed so they cannot
-        # poison the bucket-wide convergence check.
-        finite = jnp.all(jnp.isfinite(dirn), axis=1, keepdims=True)
-        dirn = jnp.where(finite, dirn, 0.0)
-        norm = jnp.linalg.norm(dirn, axis=1, keepdims=True)
-        untrusted = (norm > max_step) | ~finite
-        dirn = jnp.where(norm > max_step,
-                         dirn * (max_step / (norm + 1e-30)), dirn)
-        if guarded:
-            # An untrusted direction means the quadratic model failed there
-            # — a full clipped step from a saturated warm start can land
-            # where the next clipped step points exactly back (a period-2
-            # cycle), and a near-singular Hessian can make the direction
-            # useless at any scale. Guard with a per-node backtracking
-            # search over Newton + gradient candidates on the concave CL
-            # objective. Only warm-started solves compile this branch: the
-            # pathologies need a saturated starting point, and cold starts
-            # from zero (the benchmarked hot path) never produce one.
-            step = jax.lax.cond(
-                jnp.any(untrusted),
-                lambda: _backtrack_step(objective, W, dirn, g, max_step),
-                lambda: dirn)
-        else:
-            step = dirn
-        delta = jnp.max(jnp.abs(step))
-        if axis_name is not None:
-            delta = jax.lax.pmax(delta, axis_name)
-        return W - step, it + 1, delta
+        def newton_step(carry):
+            W, it, _, _, _ = carry
+            g_raw, K_raw = newton_stats(W)           # fused score + Gram
+            g = g_raw / denom[:, None]
+            H = -K_raw / denom[:, None, None] \
+                - ridge * eye[None, :, :] - pad_diag
+            dirn = _gauss_jordan_solve(H, g[..., None])[..., 0]  # (k, dC)
+            # an untrusted direction: non-finite (curvature underflow at a
+            # saturated point makes the solve blow up) or clipped (outside
+            # Newton's trust region). NaN directions are zeroed so they
+            # cannot poison the bucket-wide convergence check.
+            finite = jnp.all(jnp.isfinite(dirn), axis=1, keepdims=True)
+            dirn = jnp.where(finite, dirn, 0.0)
+            norm = jnp.linalg.norm(dirn, axis=1, keepdims=True)
+            untrusted = (norm > max_step) | ~finite
+            dirn = jnp.where(norm > max_step,
+                             dirn * (max_step / (norm + 1e-30)), dirn)
+            if guarded:
+                # An untrusted direction means the quadratic model failed
+                # there — a full clipped step from a saturated warm start
+                # can land where the next clipped step points exactly back
+                # (a period-2 cycle), and a near-singular Hessian can make
+                # the direction useless at any scale. Guard with a per-node
+                # backtracking search over Newton + gradient candidates on
+                # the concave CL objective. Only warm-started solves compile
+                # this branch: the pathologies need a saturated starting
+                # point, and cold starts from zero (the benchmarked hot
+                # path) never produce one.
+                step = jax.lax.cond(
+                    jnp.any(untrusted),
+                    lambda: _backtrack_step(objective, W, dirn, g, max_step),
+                    lambda: dirn)
+            else:
+                step = dirn
+            row = jnp.max(jnp.abs(step), axis=1)
+            delta = jnp.max(row)
+            live = jnp.sum(row > tol, dtype=jnp.int32)
+            if axis_name is not None:
+                delta = jax.lax.pmax(delta, axis_name)
+                live = jax.lax.psum(live, axis_name)
+            return W - step, it + 1, delta, live, row
 
-    W, iters, _ = jax.lax.while_loop(cond, newton_step, (W0, 0, jnp.inf))
+        start = (W, it, delta, jnp.int32(np.iinfo(np.int32).max),
+                 jnp.full(W.shape[:1], jnp.inf, cdtype))
+        return jax.lax.while_loop(cond, newton_step, start)
+
+    # A large bucket iterates whole until no more than _STRAGGLER_ROWS rows
+    # still step above tol; those rows alone then finish under the same
+    # budget, so one slow site (a large or barely identified local MLE)
+    # no longer costs all the bucket's rows its extra iterations.
+    rows = (Zb, base, xi, sw, denom, pad_diag)
+    split = k > _SPLIT_ROWS
+    W, iters, delta, _, row = iterate(
+        *rows, W0, 0, jnp.inf, _STRAGGLER_ROWS if split else -1)
+    if split:
+        idx = jax.lax.top_k(row, _STRAGGLER_ROWS)[1]
+        few = [a[idx] for a in rows]
+        if not weighted:
+            few[3] = sw                              # never read
+        Wf, iters, _, _, _ = iterate(*few, W[idx], iters, delta, -1)
+        W = W.at[idx].set(Wf)
     I = jnp.full((k,), iters, dtype=jnp.int32)
 
-    # sandwich diagnostics at W_hat (closed forms again; no autodiff).
-    # Under 0/1 weights the masked-out samples' scores are zeroed, so their
-    # rows of S are exactly zero and J/H average only the live samples;
-    # consumers that normalize influence columns by the row count (the
-    # "optimal" combiner) should use the live count, not the buffer size.
+    # sandwich sums at W_hat, compensated (float32 hi + lo) so that the
+    # float64 variance the host forms from them keeps the digits an
+    # ill-conditioned fit needs. Under 0/1 weights the masked-out samples'
+    # scores are zeroed, so their rows of S are exactly zero and J/H
+    # average only the live samples; consumers that normalize influence
+    # columns by the row count (the "optimal" combiner) should use the live
+    # count, not the buffer size.
+    score_curvature, _, curvature_matrix, _, score_matrix, _ = \
+        _channel_ops(family, Zb, base, xi, sw, weighted, denom,
+                     family.sandwich_terms)
     r, kap = score_curvature(W)
     G = score_matrix(r)                                      # (k, dC, n)
-    J = G @ jnp.swapaxes(G, 1, 2) / denom[:, None, None]
-    H = curvature_matrix(kap) / denom[:, None, None]         # = -hessian
-    Hreg = H + 1e-9 * eye[None, :, :] + pad_diag
-    Hinv = _gauss_jordan_solve(Hreg, jnp.broadcast_to(eye, Hreg.shape))
-    V = Hinv @ J @ jnp.swapaxes(Hinv, 1, 2)
+    if C == 1:
+        H2 = _gram_hilo(Zb[:, 0], kap[:, 0, 0])
+        J2 = _gram_hilo(Zb[:, 0], r[:, 0] * r[:, 0])
+    else:
+        zero = jnp.zeros((k, dC, dC), cdtype)
+        H2 = jnp.stack([curvature_matrix(kap), zero], axis=1)
+        J2 = jnp.stack([G @ jnp.swapaxes(G, 1, 2), zero], axis=1)
     if want_influence:
+        H = H2[:, 0] / denom[:, None, None]                  # = -hessian
+        Hreg = H + 1e-9 * eye[None, :, :] + pad_diag
+        Hinv = _gauss_jordan_solve(Hreg, jnp.broadcast_to(eye, Hreg.shape))
         S = jnp.swapaxes(G, 1, 2) @ jnp.swapaxes(Hinv, 1, 2)  # (k, n, dC)
     else:
         # only the Linear-Opt combiner reads the (k, n, dC) per-sample
         # influence stack; a session whose combiners never request
         # "influence" skips materializing it (static branch)
         S = jnp.zeros((k, 0, dC), cdtype)
-    return W, H, J, V, S, I
+    return W, H2, J2, S, I, denom
 
 
 @functools.partial(jax.jit,
@@ -686,7 +793,7 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
             t0 = time.perf_counter()
         with rec.span("bucket_solve", deg_pad=b.deg_pad, k=k):
             if mesh is None:
-                W, H, J, V, S, I = _solve_bucket(
+                W, H, J, S, I, D = _solve_bucket(
                     X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
                     jnp.asarray(b.mask), offsets, W0, sw, include_singleton,
                     n_iter, weighted, warm_start is not None, family,
@@ -697,7 +804,7 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
                     shards, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
                     jnp.asarray(b.mask), offsets, W0)
                 sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
-                W, H, J, V, S, I = _solve_bucket_sharded(
+                W, H, J, S, I, D = _solve_bucket_sharded(
                     X, nodes_, nbrs_, mask_, offsets_, W0_, sw_,
                     include_singleton, n_iter, weighted,
                     warm_start is not None, family, mesh,
@@ -712,8 +819,8 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
             if rec.enabled:
                 # the Newton iteration counts, fetched for telemetry alone
                 # (below, outside the span), join the bucket's byte count
-                nbytes = sum(int(a.nbytes) for a in (W, H, J, V, S, I))
-            W, H, J, V, S = (np.asarray(a)[:k] for a in (W, H, J, V, S))
+                nbytes = sum(int(a.nbytes) for a in (W, H, J, S, I, D))
+            W, H, J, S, D = (np.asarray(a)[:k] for a in (W, H, J, S, D))
         if track:
             # the np.asarray conversions above block on the device work, so
             # dt covers trace+compile+execute for a compiling dispatch
@@ -726,6 +833,10 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
                         deg_pad=b.deg_pad)
             rec.inc(D2H_BYTES, nbytes, site="bucket_solve")
         with rec.span("assemble", deg_pad=b.deg_pad, k=k):
+            cmask = (np.concatenate([np.ones((k, 1), np.float32), b.mask],
+                                    axis=1) if include_singleton else b.mask)
+            H, J, V = (a.astype(W.dtype)
+                       for a in _sandwich_host(H, J, D, cmask, C))
             degs = b.mask.sum(axis=1).astype(np.int64)
             for row, i in enumerate(b.nodes):
                 i = int(i)

@@ -130,6 +130,16 @@ class ModelFamily:
         """Closed-form -d^2 loglik / d eta^2, PSD: (..., C, C, n)."""
         raise NotImplementedError
 
+    def sandwich_terms(self, eta: jnp.ndarray, xi: jnp.ndarray):
+        """(dl_deta, curvature) at the solution, for the sandwich variance.
+
+        The variance of an ill-conditioned fit amplifies the per-sample
+        error of these terms by its condition number, so a family whose
+        hooks lose digits (a cancellation, an approximate transcendental)
+        overrides this with a more careful form. Default: the hooks.
+        """
+        return self.dl_deta(eta, xi), self.curvature(eta, xi)
+
     # --------------------------------------------------- sampling hooks
     def init_draw(self, key: jax.Array, p: int) -> jnp.ndarray:
         """(p,) initial Gibbs state."""

@@ -29,8 +29,34 @@ class Graph:
             if (i, j) in seen:
                 raise ValueError(f"duplicate edge ({i},{j})")
             seen.add((i, j))
+        self._build_incidence()
 
-    # ---- derived structure (cached via object.__setattr__ lazily) ----
+    def _build_incidence(self) -> None:
+        """CSR incidence index, built once in O(m log m): node i's incident
+        edge ids (ascending, i.e. ``edges`` order) are ``_inc_edge[
+        _inc_ptr[i]:_inc_ptr[i + 1]]`` and ``_inc_other`` holds the other
+        endpoint of each. Plain attributes, not dataclass fields, so
+        equality, hashing and ``repr`` still see only ``(p, edges)``."""
+        ends = np.asarray(self.edges, dtype=np.int64).reshape(-1, 2)
+        ids = np.arange(len(ends), dtype=np.int64)
+        node = np.concatenate([ends[:, 0], ends[:, 1]])
+        edge = np.concatenate([ids, ids])
+        other = np.concatenate([ends[:, 1], ends[:, 0]])
+        order = np.lexsort((edge, node))
+        ptr = np.zeros(self.p + 1, dtype=np.int64)
+        np.cumsum(np.bincount(node, minlength=self.p), out=ptr[1:])
+        for name, arr in (("_inc_ptr", ptr), ("_inc_edge", edge[order]),
+                          ("_inc_other", other[order])):
+            arr.flags.writeable = False
+            object.__setattr__(self, name, arr)
+
+    def incidence(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """The incidence index ``(ptr, edge, other)`` as read-only arrays:
+        node i's incident edge ids, in ``edges`` order, are
+        ``edge[ptr[i]:ptr[i + 1]]`` and ``other`` holds the other endpoint
+        of each."""
+        return self._inc_ptr, self._inc_edge, self._inc_other
+
     @property
     def m(self) -> int:
         return len(self.edges)
@@ -53,20 +79,15 @@ class Graph:
         return {e: k for k, e in enumerate(self.edges)}
 
     def neighbors(self, i: int) -> List[int]:
-        out = []
-        for (a, b) in self.edges:
-            if a == i:
-                out.append(b)
-            elif b == i:
-                out.append(a)
-        return sorted(out)
+        lo, hi = self._inc_ptr[i], self._inc_ptr[i + 1]
+        return sorted(self._inc_other[lo:hi].tolist())
 
     def degree(self, i: int) -> int:
-        return len(self.neighbors(i))
+        return int(self._inc_ptr[i + 1] - self._inc_ptr[i])
 
     def incident_edges(self, i: int) -> List[int]:
         """Edge-block indices of edges touching node i (in edges order)."""
-        return [k for k, (a, b) in enumerate(self.edges) if i in (a, b)]
+        return self._inc_edge[self._inc_ptr[i]:self._inc_ptr[i + 1]].tolist()
 
     def beta(self, i: int, include_singleton: bool = True) -> List[int]:
         """Flat-parameter indices in beta_i = {alpha : i in alpha}.

@@ -11,10 +11,11 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...telemetry.recorder import record_d2h
+from ...telemetry.recorder import active_span, record_d2h
 from .kernel import cl_score_channels, target_platform
 
 
@@ -86,9 +87,13 @@ def fused_pseudo_score(family, graph, theta, x_pad, n_seen: int, *,
                                    interpret=interpret,
                                    use_pallas=use_pallas)
     n_seen = int(n_seen)
-    record_d2h("score", S, r)
-    S = np.asarray(S, dtype=np.float64) * (x_pad.shape[0] / max(n_seen, 1))
-    r = np.asarray(r, dtype=np.float64)[:, :n_seen, :]     # live rows only
+    # the wait for the kernel stays outside the fetch's span
+    jax.block_until_ready((r, S))
+    with active_span("score_fetch"):
+        record_d2h("score", S, r)
+        S = np.asarray(S, dtype=np.float64) * (x_pad.shape[0]
+                                               / max(n_seen, 1))
+        r = np.asarray(r, dtype=np.float64)[:, :n_seen, :]  # live rows only
     g = np.zeros(family.n_params(graph))
     g[: p * C] = (r.sum(axis=1) / max(n_seen, 1)).T.reshape(p * C)
     for k, (i, j) in enumerate(graph.edges):

@@ -29,8 +29,9 @@ While any real span is open the recorder is also *active* for trace-time
 kernel tags: :func:`record_kernel_trace`, called from the kernel dispatch
 layer (``repro.kernels.cl.ops``) during jit tracing, lands kernel-kind and
 shape events on the innermost active recorder; :func:`record_d2h` counts a
-device-to-host fetch made by code that holds no recorder. With no active
-recorder either hook is a single falsy list check.
+device-to-host fetch made by code that holds no recorder, and
+:func:`active_span` opens a child span for such code. With no active
+recorder each hook is a single falsy list check.
 """
 from __future__ import annotations
 
@@ -45,7 +46,8 @@ from .sinks import JsonlSink
 from .spec import TelemetrySpec
 
 __all__ = ["NullRecorder", "NULL_RECORDER", "Recorder", "TelemetrySnapshot",
-           "make_recorder", "record_kernel_trace", "record_d2h", "D2H_BYTES"]
+           "make_recorder", "record_kernel_trace", "record_d2h",
+           "active_span", "D2H_BYTES"]
 
 #: counter of device-to-host bytes on the estimate path: one increment per
 #: fetch group (one host round trip), tagged with the ``site`` that fetched
@@ -122,6 +124,14 @@ def record_d2h(site: str, *arrays) -> None:
     if _ACTIVE:
         _ACTIVE[-1].inc(D2H_BYTES, sum(int(a.nbytes) for a in arrays),
                         site=site)
+
+
+def active_span(name: str, **tags):
+    """A child span of the innermost active recorder's open span, for code
+    that holds no recorder; the shared null span with telemetry off."""
+    if _ACTIVE:
+        return _ACTIVE[-1].span(name, **tags)
+    return _NULL_SPAN
 
 
 def _bucket_compiles() -> int:

@@ -8,6 +8,7 @@ path: one increment per host round trip, valued at the device arrays'
 bytes.
 """
 import glob
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -15,7 +16,8 @@ import pytest
 
 import repro.api as A
 from repro.core.batched import degree_buckets
-from repro.core.graphs import chain_graph, scale_free_graph, star_graph
+from repro.core.graphs import (chain_graph, grid_graph, scale_free_graph,
+                               star_graph)
 from repro.telemetry import Recorder, TelemetrySpec
 from repro.telemetry.recorder import _ACTIVE, D2H_BYTES
 
@@ -76,7 +78,8 @@ def test_fit_annotations_nest_on_the_calling_thread(tmp_path):
            if e[2] == "fit" or e[2].startswith("fit/")]
     names = {name for _, _, name, _ in evs}
     assert names == {"fit", "fit/bucket_prep", "fit/bucket_solve",
-                     "fit/assemble", "fit/combine", "fit/score"} | {
+                     "fit/assemble", "fit/combine", "fit/score",
+                     "fit/score/score_fetch"} | {
         f"fit/combine/{s}" for s in SCHEMES}
     (fit,) = [e for e in evs if e[2] == "fit"]
     call = fit[3]["call"]
@@ -150,8 +153,10 @@ def test_d2h_bytes_match_the_fleet_shapes():
     want = []
     for b in degree_buckets(g):
         k, d = len(b.nodes), b.deg_pad + 1
-        # W (k, d); H, J, V (k, d, d); S (k, n, d); Newton iterations (k,)
-        want.append(f32 * (k * d + 3 * k * d * d + k * n * d) + 4 * k)
+        # W (k, d); H, J as compensated pairs (k, 2, d, d); S (k, n, d);
+        # Newton iterations (k,); sample counts (k,)
+        want.append(f32 * (k * d + 4 * k * d * d + k * n * d) + 4 * k
+                    + f32 * k)
     want.append(f32 * (n * g.p + g.p * g.p))       # score: r (1, n, p), Gram
     got = [e for e in res.telemetry.events
            if e["kind"] == "counter" and e["name"] == D2H_BYTES]
@@ -174,3 +179,53 @@ def test_joint_spans_and_prox_fetches():
     assert snap.spans["joint/score"]["count"] == 1
     assert snap.counter(D2H_BYTES, site="prox_bucket_solve") > 0
     assert snap.counter(D2H_BYTES, site="score") > 0
+
+
+def test_score_fetch_span_holds_the_score_fetch():
+    """The score's fetch to the host, and its counter increment, sit in a
+    ``score_fetch`` span inside ``score``; the benchmark's reader of that
+    span reads it per call."""
+    from bench.harness import Context, Layout, Window
+
+    g = grid_graph(6, 6)
+    n = 512
+    sess = A.Plan(graph=g, combiners=("diagonal",),
+                  telemetry=TelemetrySpec()).session()
+    snaps = [sess.fit(_pm1(n, g.p, seed)).telemetry for seed in (1, 2)]
+    for snap in snaps:
+        fetch = snap.spans["fit/score/score_fetch"]
+        assert fetch["count"] == 1
+        assert 0.0 < fetch["total_s"] <= snap.spans["fit/score"]["total_s"]
+        (start,) = [e for e in snap.events if e["kind"] == "span_start"
+                    and e["name"] == "fit/score/score_fetch"]
+        (end,) = [e for e in snap.events if e["kind"] == "span_end"
+                  and e["name"] == "fit/score/score_fetch"]
+        (score,) = [e for e in snap.events if e["kind"] == "counter"
+                    and e["name"] == D2H_BYTES
+                    and e["tags"]["site"] == "score"]
+        assert start["seq"] < score["seq"] < end["seq"]
+        # the bytes counted are the same: r (1, n, p) and the Gram (p, p)
+        assert score["value"] == 4 * (n * g.p + g.p * g.p)
+        # W, the compensated H and J pairs, the Newton counts and sample
+        # counts of each bucket; the diagonal combiner reads no influence
+        # stacks, so S comes back (k, 0, d)
+        buckets = sum(4 * (k * d + 4 * k * d * d) + 4 * k + 4 * k
+                      for k, d in ((len(b.nodes), b.deg_pad + 1)
+                                   for b in degree_buckets(g)))
+        assert snap.counters[D2H_BYTES] == buckets + score["value"]
+
+    layout = Layout(Path(__file__).resolve().parents[2])
+    ctx = Context(cell={}, config={}, traffic={},
+                  window=Window(attempted=2, failed=0, end_to_end={}),
+                  trace=None, telemetry=snaps, work={}, peak=None)
+    got = layout.metric("score_fetch_ms").read(ctx)
+    assert got == pytest.approx(1e3 * np.mean(
+        [s.spans["fit/score/score_fetch"]["total_s"] for s in snaps]))
+    assert got > 0.0
+
+
+def test_joint_score_has_its_fetch_span():
+    res = A.Plan(graph=chain_graph(5), combiners=("diagonal",),
+                 admm_iters=2, telemetry=TelemetrySpec()
+                 ).session().joint(_pm1(150, 5))
+    assert res.telemetry.spans["joint/score/score_fetch"]["count"] == 1
