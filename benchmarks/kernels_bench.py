@@ -233,8 +233,9 @@ def bench_bucket_newton():
                     _k, *a, chunk=cfg.bm)
             return _time(fn, *_a, reps=2)[0]
 
-        tiles, timings = search_tiles("newton", n=n, p=d, C=Cc,
-                                      measure=measure)
+        tiles, timings = search_tiles(
+            "newton", n=n, p=d, C=Cc, measure=measure,
+            k=k if path == "mosaic" else None)
         if path == "mosaic":
             comp = lambda *a, _k=kind: bucket_newton_stats(  # noqa: E731
                 _k, *a, interpret=False, tiles=tiles)

@@ -66,7 +66,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.cl.epilogues import get_epilogue
-from ..kernels.cl.ops import bucket_newton_stats_op
+from ..kernels.cl.ops import bucket_newton_op
 from ..telemetry.recorder import D2H_BYTES, NULL_RECORDER
 from .estimators import LocalFit
 from .families import ISING
@@ -319,22 +319,12 @@ def _channel_ops(family, Zb, base, xi, sw, weighted, denom, terms=None):
     so each family compiles only its own form.
 
     Returns ``(score_curvature, grad_vec, curvature_matrix, avg_loglik,
-    score_matrix, newton_stats)``: per-sample channel score/curvature at a
-    flat W, the flat gradient vector from a channel score, the (k, dC, dC)
-    curvature matrix from a channel curvature, the (c, k) per-node average
-    loglik of a candidate stack, the (k, dC, n) per-sample score matrix,
-    and the fused Newton statistics ``W -> (g_raw, K_raw)``. ``terms``
-    replaces the family's score and curvature hooks in ``score_curvature``
-    (the sandwich passes ``family.sandwich_terms``).
-
-    ``newton_stats`` is the per-iteration hot path: for families with a
-    registered fused-kernel epilogue (``family.kernel_kind``) it goes
-    through :func:`repro.kernels.cl.ops.bucket_newton_stats_op` — the fused
-    score + Gram entry emitting both directly in this (k, C, d) bucket
-    layout (compiled Pallas on TPU, the bit-identical jnp reference
-    elsewhere) without materializing the per-sample residual/curvature
-    between contractions; families without an epilogue fall back to the
-    closed-form hook closures.
+    score_matrix)``: per-sample channel score/curvature at a flat W, the
+    flat gradient vector from a channel score, the (k, dC, dC) curvature
+    matrix from a channel curvature, the (c, k) per-node average loglik of
+    a candidate stack, and the (k, dC, n) per-sample score matrix.
+    ``terms`` replaces the family's score and curvature hooks in
+    ``score_curvature`` (the sandwich passes ``family.sandwich_terms``).
     """
     k, C, d, _ = Zb.shape
     dC = d * C
@@ -389,18 +379,33 @@ def _channel_ops(family, Zb, base, xi, sw, weighted, denom, terms=None):
         return jnp.transpose(Zb * r[:, :, None, :],
                              (0, 2, 1, 3)).reshape(k, dC, n)
 
+    return score_curvature, grad_vec, curvature_matrix, avg_loglik, \
+        score_matrix
+
+
+def _bucket_newton(family, Zb, base, xi, sw, weighted, denom):
+    """The per-iteration Newton statistics ``W -> (g_raw, K_raw)``.
+
+    Call it before the Newton loop and the result inside it. For families
+    with a registered fused-kernel epilogue (``family.kernel_kind``) this
+    goes through :func:`repro.kernels.cl.ops.bucket_newton_op`, which lays
+    the bucket out for the kernel here, once, and emits score and Gram
+    directly in this (k, C, d) bucket layout (compiled Pallas on TPU, the
+    bit-identical jnp reference elsewhere) without materializing the
+    per-sample residual/curvature between contractions; families without
+    an epilogue fall back to the closed-form hook closures.
+    """
     kind = getattr(family, "kernel_kind", None)
-    fused_kind = kind if get_epilogue(kind) is not None else None
+    if get_epilogue(kind) is not None:
+        return bucket_newton_op(kind, Zb, base, xi, sw if weighted else None)
+    score_curvature, grad_vec, curvature_matrix, *_ = _channel_ops(
+        family, Zb, base, xi, sw, weighted, denom)
 
     def newton_stats(W):
-        if fused_kind is not None:
-            return bucket_newton_stats_op(fused_kind, Zb, base, xi, W,
-                                          sw if weighted else None)
         r, kap = score_curvature(W)
         return grad_vec(r), curvature_matrix(kap)
 
-    return score_curvature, grad_vec, curvature_matrix, avg_loglik, \
-        score_matrix, newton_stats
+    return newton_stats
 
 
 @_float32_contractions
@@ -471,8 +476,10 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     def iterate(Zb, base, xi, sw, denom, pad_diag, W, it, delta, stop):
         """Damped Newton on these rows from W: while under the budget, some
         row's step is above ``tol`` and more than ``stop`` rows are."""
-        *_, objective, _, newton_stats = _channel_ops(
-            family, Zb, base, xi, sw, weighted, denom)
+        objective = _channel_ops(family, Zb, base, xi, sw, weighted,
+                                 denom)[3]
+        newton_stats = _bucket_newton(family, Zb, base, xi, sw, weighted,
+                                      denom)
 
         def cond(carry):
             _, it, delta, live, _ = carry
@@ -548,9 +555,8 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     # average only the live samples; consumers that normalize influence
     # columns by the row count (the "optimal" combiner) should use the live
     # count, not the buffer size.
-    score_curvature, _, curvature_matrix, _, score_matrix, _ = \
-        _channel_ops(family, Zb, base, xi, sw, weighted, denom,
-                     family.sandwich_terms)
+    score_curvature, _, curvature_matrix, _, score_matrix = _channel_ops(
+        family, Zb, base, xi, sw, weighted, denom, family.sandwich_terms)
     r, kap = score_curvature(W)
     G = score_matrix(r)                                      # (k, dC, n)
     if C == 1:
@@ -883,8 +889,8 @@ def _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho,
     else:
         denom = jnp.full((k,), float(n), cdtype)
 
-    score_curvature, grad_vec, curvature_matrix, avg_loglik, _, \
-        newton_stats = _channel_ops(family, Zb, base, xi, sw, weighted, denom)
+    avg_loglik = _channel_ops(family, Zb, base, xi, sw, weighted, denom)[3]
+    newton_stats = _bucket_newton(family, Zb, base, xi, sw, weighted, denom)
 
     def objective(Ws):
         # (c, k): penalized criterion for a stack of candidate points

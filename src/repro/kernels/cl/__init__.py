@@ -39,8 +39,9 @@ from .autotune import (CHUNK_MIN_N, KERNEL_OPS, TileConfig, cache_snapshot,
 from .epilogues import (Epilogue, get_epilogue, register_epilogue,
                         registered_kinds)
 from .kernel import cl_logits, cl_score_channels, ising_cl_logits
-from .newton import bucket_newton_stats, bucket_newton_stats_ref
-from .ops import (KERNEL_PATHS, bucket_newton_stats_op, conditional_logits_op,
+from .newton import (bucket_newton_prepare, bucket_newton_stats,
+                     bucket_newton_stats_ref)
+from .ops import (KERNEL_PATHS, bucket_newton_op, conditional_logits_op,
                   default_kernel_path, resolve_kernel_path,
                   score_stats_channels_op, score_stats_op)
 from .precision import PRECISION_TOLERANCES, precision_tolerance
@@ -58,10 +59,10 @@ __all__ = [
     "ising_cl_score", "ising_cl_score_padded", "KERNEL_KINDS",
     "cl_score_ref", "cl_score_channels_ref", "cl_logits_ref",
     "ising_cl_logits_ref", "ising_cl_score_ref",
-    "bucket_newton_stats", "bucket_newton_stats_ref",
+    "bucket_newton_prepare", "bucket_newton_stats", "bucket_newton_stats_ref",
     "cl_score_channels_tiled", "bucket_newton_stats_tiled",
     "conditional_logits_op", "score_stats_op", "score_stats_channels_op",
-    "bucket_newton_stats_op", "KERNEL_PATHS", "default_kernel_path",
+    "bucket_newton_op", "KERNEL_PATHS", "default_kernel_path",
     "resolve_kernel_path",
     "TileConfig", "KERNEL_OPS", "CHUNK_MIN_N", "get_tiles", "search_tiles",
     "candidate_tiles", "validate_tile_config", "tile_key", "save_cache",

@@ -18,9 +18,11 @@ win depends on the operand shape and the backend, so the dispatch layer
 
 Keys are ``(op, backend, dtype, n, p, C)`` — ``op`` is ``"score"`` or
 ``"newton"``, ``p`` doubles as the bucket design width ``d`` for the
-newton op. The cache round-trips through JSON (:func:`save_cache` /
-:func:`load_cache`); setting ``REPRO_CL_TUNE_CACHE=/path.json`` loads that
-file lazily on first lookup and appends every search result to it.
+newton op, whose TPU keys add the bucket's node count ``k`` and whether
+the fit is weighted (the node block depends on both). The cache
+round-trips through JSON (:func:`save_cache` / :func:`load_cache`);
+setting ``REPRO_CL_TUNE_CACHE=/path.json`` loads that file lazily on
+first lookup and appends every search result to it.
 
 Search is bounded by construction: candidate lists are a handful of
 MXU-aligned configs per (op, backend), every candidate is validated by
@@ -36,6 +38,9 @@ import threading
 from typing import Callable, Dict, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
+
+from .newton import newton_tile_rule
 
 __all__ = [
     "TileConfig", "KERNEL_OPS", "validate_tile_config", "candidate_tiles",
@@ -66,11 +71,15 @@ class TileConfig:
         order.
     bn : output-column tile of the score kernel (ignored by newton).
     bk : contraction tile of the score kernel (ignored by newton).
+    kb : node block of the TPU newton kernel: bucket nodes per grid step.
+        ``None`` (as in a cache entry written before the field existed)
+        means "the shape heuristic's block for this ``bm``".
     """
 
     bm: Optional[int] = 128
     bn: int = 128
     bk: int = 128
+    kb: Optional[int] = None
 
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -78,7 +87,7 @@ class TileConfig:
     @classmethod
     def from_dict(cls, d: dict) -> "TileConfig":
         return cls(bm=d.get("bm"), bn=int(d.get("bn", 128)),
-                   bk=int(d.get("bk", 128)))
+                   bk=int(d.get("bk", 128)), kb=d.get("kb"))
 
 
 def validate_tile_config(cfg: TileConfig, op: str,
@@ -88,8 +97,10 @@ def validate_tile_config(cfg: TileConfig, op: str,
     ``compiled=True`` applies the Mosaic (real-TPU) constraints on top of
     the structural ones: 128-multiple score lane tiles and an explicit
     (8-aligned) sample tile, 128-aligned for newton, whose blocks carry
-    the sample axis on the lanes. Interpret mode and the compiled-CPU
-    twins only need positive sizes.
+    the sample axis on the lanes, and an 8-multiple newton node block,
+    whose blocks carry the nodes on the sublanes (a block of at least the
+    whole bucket is cut to the bucket by the kernel). Interpret mode and
+    the compiled-CPU twins only need positive sizes.
     """
     if op not in KERNEL_OPS:
         raise ValueError(f"unknown kernel op {op!r}; choose from "
@@ -103,6 +114,9 @@ def validate_tile_config(cfg: TileConfig, op: str,
     for name, v in (("bn", cfg.bn), ("bk", cfg.bk)):
         if not isinstance(v, int) or v < 1:
             raise ValueError(f"{name} must be a positive int, got {v!r}")
+    if cfg.kb is not None and (not isinstance(cfg.kb, int) or cfg.kb < 1):
+        raise ValueError(f"kb must be a positive int or None, got "
+                         f"{cfg.kb!r}")
     if compiled:
         if cfg.bm is None or cfg.bm % 8:
             raise ValueError(
@@ -112,6 +126,10 @@ def validate_tile_config(cfg: TileConfig, op: str,
             raise ValueError(
                 f"compiled newton kernel tiles the sample axis on the "
                 f"lanes and needs a 128-multiple bm, got bm={cfg.bm!r}")
+        if op == "newton" and cfg.kb is not None and cfg.kb % 8:
+            raise ValueError(
+                f"compiled newton kernel blocks the bucket nodes on the "
+                f"sublanes and needs an 8-multiple kb, got kb={cfg.kb!r}")
         if op == "score" and (cfg.bn % 128 or cfg.bk % 128):
             raise ValueError(
                 f"compiled score kernel needs 128-multiple lane tiles, got "
@@ -120,13 +138,19 @@ def validate_tile_config(cfg: TileConfig, op: str,
 
 
 def tile_key(op: str, *, n: int, p: int, C: int,
-             backend: Optional[str] = None, dtype: str = "float32") -> str:
-    """The canonical cache key string for one (op, shape, backend, dtype)."""
+             backend: Optional[str] = None, dtype: str = "float32",
+             k: Optional[int] = None, weighted: bool = False) -> str:
+    """The canonical cache key string for one (op, shape, backend, dtype);
+    a newton key given the bucket's node count ``k`` also names it and
+    whether the fit is weighted."""
     if op not in KERNEL_OPS:
         raise ValueError(f"unknown kernel op {op!r}; choose from "
                          f"{KERNEL_OPS}")
     backend = backend or jax.default_backend()
-    return f"{op}|{backend}|{dtype}|n={int(n)}|p={int(p)}|C={int(C)}"
+    key = f"{op}|{backend}|{dtype}|n={int(n)}|p={int(p)}|C={int(C)}"
+    if k is not None:
+        key += f"|k={int(k)}|w={int(bool(weighted))}"
+    return key
 
 
 # ------------------------------------------------------------------ caches
@@ -196,15 +220,23 @@ def _load_env_cache() -> None:
 
 
 # -------------------------------------------------------------- heuristics
-def _default_tiles(op: str, *, n: int, p: int, C: int,
-                   backend: str) -> TileConfig:
+def _default_tiles(op: str, *, n: int, p: int, C: int, backend: str,
+                   k: Optional[int] = None, weighted: bool = False,
+                   dtype: str = "float32") -> TileConfig:
     """Shape heuristic used when nothing tuned is cached. Deterministic.
 
-    TPU: MXU-aligned 128s everywhere.
+    TPU newton: the node block and sample tile of
+    :func:`.newton.newton_tile_rule` for a bucket of ``k`` nodes (one node
+    when ``k`` is not given). TPU score: MXU-aligned 128s.
     CPU: the compiled-CPU twins — whole-axis (== the jnp reference
     contraction, golden-bit-stable) below :data:`CHUNK_MIN_N` samples,
     cache-sized 1024-sample chunks above it.
     """
+    if backend == "tpu" and op == "newton":
+        kb, bm = newton_tile_rule(1 if k is None else int(k), p, C, n,
+                                  weighted,
+                                  itemsize=jnp.dtype(dtype).itemsize)
+        return TileConfig(bm=bm, kb=kb)
     if backend == "tpu":
         return TileConfig(bm=128, bn=128, bk=128)
     if op == "newton" and n >= CHUNK_MIN_N:
@@ -213,19 +245,25 @@ def _default_tiles(op: str, *, n: int, p: int, C: int,
 
 
 def candidate_tiles(op: str, *, n: int, p: int, C: int,
-                    backend: Optional[str] = None) -> Tuple[TileConfig, ...]:
+                    backend: Optional[str] = None, k: Optional[int] = None,
+                    weighted: bool = False, dtype: str = "float32"
+                    ) -> Tuple[TileConfig, ...]:
     """The bounded search space for one key, heuristic default first.
 
     Small by design — the tuner is a measured tiebreak between a handful of
     MXU-aligned configs, not a general scheduler. Candidates whose chunk
     would exceed the sample axis are dropped (they alias the whole-axis
-    config).
+    config). TPU newton candidates halve and double the default's sample
+    tile, each with the rule's node block for it.
     """
     backend = backend or jax.default_backend()
-    default = _default_tiles(op, n=n, p=p, C=C, backend=backend)
+    default = _default_tiles(op, n=n, p=p, C=C, backend=backend, k=k,
+                             weighted=weighted, dtype=dtype)
     if backend == "tpu":
         if op == "newton":
-            cands = [default] + [TileConfig(bm=bm) for bm in (256, 512)]
+            cands = [default] + [TileConfig(bm=bm) for bm in
+                                 (default.bm // 2, default.bm * 2)
+                                 if bm >= 128]
         else:
             cands = [default,
                      TileConfig(bm=256, bn=128, bk=128),
@@ -249,8 +287,9 @@ def candidate_tiles(op: str, *, n: int, p: int, C: int,
 
 # ----------------------------------------------------------------- entries
 def get_tiles(op: str, *, n: int, p: int, C: int,
-              backend: Optional[str] = None,
-              dtype: str = "float32") -> TileConfig:
+              backend: Optional[str] = None, dtype: str = "float32",
+              k: Optional[int] = None,
+              weighted: bool = False) -> TileConfig:
     """Resolve tiles for one key without measuring anything.
 
     Lookup order: in-process cache -> ``REPRO_CL_TUNE_CACHE`` JSON (loaded
@@ -259,7 +298,8 @@ def get_tiles(op: str, *, n: int, p: int, C: int,
     jit traces of one shape can never flip tiles between retraces.
     """
     backend = backend or jax.default_backend()
-    key = tile_key(op, n=n, p=p, C=C, backend=backend, dtype=dtype)
+    key = tile_key(op, n=n, p=p, C=C, backend=backend, dtype=dtype, k=k,
+                   weighted=weighted)
     with _LOCK:
         hit = _CACHE.get(key)
     if hit is not None:
@@ -268,7 +308,8 @@ def get_tiles(op: str, *, n: int, p: int, C: int,
     with _LOCK:
         hit = _CACHE.get(key)
         if hit is None:
-            hit = _default_tiles(op, n=n, p=p, C=C, backend=backend)
+            hit = _default_tiles(op, n=n, p=p, C=C, backend=backend, k=k,
+                                 weighted=weighted, dtype=dtype)
             _CACHE[key] = hit
     return hit
 
@@ -277,6 +318,7 @@ def search_tiles(op: str, *, n: int, p: int, C: int,
                  measure: Callable[[TileConfig], float],
                  backend: Optional[str] = None, dtype: str = "float32",
                  candidates: Optional[Sequence[TileConfig]] = None,
+                 k: Optional[int] = None, weighted: bool = False,
                  ) -> Tuple[TileConfig, Dict[str, float]]:
     """Measured tile search; returns ``(best, timings)``.
 
@@ -293,14 +335,16 @@ def search_tiles(op: str, *, n: int, p: int, C: int,
     to that JSON file so later processes skip the search too.
     """
     backend = backend or jax.default_backend()
-    key = tile_key(op, n=n, p=p, C=C, backend=backend, dtype=dtype)
+    key = tile_key(op, n=n, p=p, C=C, backend=backend, dtype=dtype, k=k,
+                   weighted=weighted)
     _load_env_cache()
     with _LOCK:
         hit = _CACHE.get(key)
     if hit is not None:
         return hit, {}
     cands = tuple(candidates) if candidates is not None else \
-        candidate_tiles(op, n=n, p=p, C=C, backend=backend)
+        candidate_tiles(op, n=n, p=p, C=C, backend=backend, k=k,
+                        weighted=weighted, dtype=dtype)
     if not cands:
         raise ValueError(f"no tile candidates for key {key!r}")
     compiled = backend == "tpu"

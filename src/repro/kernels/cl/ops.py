@@ -36,7 +36,7 @@ from typing import Optional
 from ...telemetry.recorder import record_kernel_trace
 from .autotune import TileConfig, get_tiles
 from .kernel import cl_score_channels, ising_cl_logits, target_platform
-from .newton import bucket_newton_stats, bucket_newton_stats_ref
+from .newton import bucket_newton_prepare, bucket_newton_stats_ref
 from .ref import cl_score_channels_ref, cl_score_ref, ising_cl_logits_ref
 from .score import cl_score
 from .tiled import bucket_newton_stats_tiled, cl_score_channels_tiled
@@ -149,31 +149,42 @@ def score_stats_channels_op(F, theta, mask, bias, *, kind: str,
     return cl_score_channels_ref(F, theta, mask, bias, kind=kind)
 
 
-def bucket_newton_stats_op(kind, Zb, base, xi, W, sw=None, *,
-                           use_pallas=None,
-                           interpret: Optional[bool] = None):
-    """Fused bucket Newton statistics (g, K), backend-aware.
+def bucket_newton_op(kind, Zb, base, xi, sw=None, *, use_pallas=None,
+                     interpret: Optional[bool] = None):
+    """Fused bucket Newton statistics, backend-aware: returns a W -> (g, K)
+    call for this bucket.
 
-    Mosaic on TPU (sample tile from the autotuner), the XLA-compiled
-    chunked twin on CPU, plain ref / interpret on request.
-    Safe to call inside a jit trace — the path choice is a trace-time
-    constant.
+    Mosaic on TPU, the XLA-compiled chunked twin on CPU, plain ref /
+    interpret on request. On the Pallas paths the bucket is laid out for
+    the kernel here, once (:func:`.newton.bucket_newton_prepare`, node
+    block and sample tile from the autotuner), so a Newton loop calls this
+    before it starts and the result once per iteration. Safe to call inside
+    a jit trace — the path choice is a trace-time constant. The telemetry
+    event carries the kernel's ``node_block``, ``bm`` and ``grid_steps``
+    (grid steps per call); paths without a grid leave them ``None``.
     """
     path = resolve_kernel_path(use_pallas, interpret)
-    record_kernel_trace("kernel.bucket_newton_stats", kind=kind,
-                        backend=path, shape=tuple(Zb.shape))
     k, C, d, n = Zb.shape
-    if path == "mosaic":
-        tiles = _tiles_for("newton", path, n=n, p=d, C=C, dtype=Zb.dtype)
-        return bucket_newton_stats(kind, Zb, base, xi, W, sw,
-                                   interpret=False, tiles=tiles)
-    if path == "interpret":
-        return bucket_newton_stats(kind, Zb, base, xi, W, sw,
-                                   interpret=True)
+    tags = dict(kind=kind, backend=path, shape=tuple(Zb.shape))
+    if path in ("mosaic", "interpret"):
+        tiles = None
+        if path == "mosaic":
+            tiles = get_tiles("newton", n=n, p=d, C=C, k=k,
+                              weighted=sw is not None, backend="tpu",
+                              dtype=str(Zb.dtype))
+        stats = bucket_newton_prepare(kind, Zb, base, xi, sw,
+                                      interpret=path == "interpret",
+                                      tiles=tiles)
+        record_kernel_trace("kernel.bucket_newton_stats", **tags,
+                            node_block=stats.node_block, bm=stats.bm,
+                            grid_steps=stats.grid_steps)
+        return stats
+    record_kernel_trace("kernel.bucket_newton_stats", **tags,
+                        node_block=None, bm=None, grid_steps=None)
     if path == "tiled":
         tiles = _tiles_for("newton", path, n=n, p=d, C=C, dtype=Zb.dtype)
         if tiles.bm is not None and tiles.bm < n:
-            return bucket_newton_stats_tiled(kind, Zb, base, xi, W, sw,
-                                             chunk=tiles.bm)
+            return lambda W: bucket_newton_stats_tiled(
+                kind, Zb, base, xi, W, sw, chunk=tiles.bm)
         # whole-axis tiled == the reference contraction, bit-identical
-    return bucket_newton_stats_ref(kind, Zb, base, xi, W, sw)
+    return lambda W: bucket_newton_stats_ref(kind, Zb, base, xi, W, sw)

@@ -3,9 +3,10 @@
 The acceptance contract of the tiling: zero padding is provably
 invisible —
 
-* the bucket Newton kernel's sample tile need not divide n (padded design
-  columns are zero, so every contribution vanishes term-by-term), for
-  every family epilogue, weighted or not;
+* the bucket Newton kernel's sample tile need not divide n, nor its node
+  block the bucket (padded design columns and rows are zero, so every
+  contribution vanishes term-by-term), for every family epilogue,
+  weighted or not;
 * the ``(j, i, k)`` score-kernel grid handles shapes that do NOT divide
   the tile sizes (edge tiles) exactly like shapes that do.
 
@@ -43,41 +44,46 @@ def _newton_case(kind, k, C, d, n, seed, weighted):
     return Zb, base, xi, W, sw
 
 
-def _check_newton(kind, C, k, d, n, bm, weighted, seed):
+def _check_newton(kind, C, k, d, n, bm, weighted, seed, kb=8):
     Zb, base, xi, W, sw = _newton_case(kind, k, C, d, n, seed, weighted)
     g0, K0 = bucket_newton_stats_ref(kind, Zb, base, xi, W, sw)
     g1, K1 = bucket_newton_stats(kind, Zb, base, xi, W, sw, interpret=True,
-                                 tiles=TileConfig(bm=bm))
+                                 tiles=TileConfig(bm=bm, kb=kb))
     assert g1.shape == g0.shape and K1.shape == K0.shape
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g0), atol=2e-5)
     np.testing.assert_allclose(np.asarray(K1), np.asarray(K0), atol=2e-5)
 
 
 @given(d=st.integers(1, 6), n=st.integers(1, 50),
-       bm=st.sampled_from([8, 16, 32]),
+       bm=st.sampled_from([8, 16, 32]), k=st.integers(1, 20),
+       kb=st.sampled_from([1, 3, 8, 16]),
        weighted=st.booleans(), seed=st.integers(0, 2 ** 16))
 @settings(max_examples=12, deadline=None)
-def test_newton_edge_tiles_match_ref_potts(d, n, bm, weighted, seed):
+def test_newton_edge_tiles_match_ref_potts(d, n, bm, k, kb, weighted, seed):
     """Interpret-mode bucket Newton with a sample tile that does not
-    divide n == the jnp reference (multi-channel, channel-major blocks
-    permuted back to the coordinate-major flat layout)."""
-    _check_newton("potts", 2, 2, d, n, bm, weighted, seed)
+    divide n and a node block that does not divide k == the jnp
+    reference (multi-channel, channel-major blocks permuted back to the
+    coordinate-major flat layout)."""
+    _check_newton("potts", 2, k, d, n, bm, weighted, seed, kb)
 
 
 @given(d=st.integers(1, 8), n=st.integers(1, 60),
-       bm=st.sampled_from([8, 16, 128]), seed=st.integers(0, 2 ** 16))
+       bm=st.sampled_from([8, 16, 128]), k=st.integers(1, 20),
+       kb=st.sampled_from([1, 3, 8, 16]), seed=st.integers(0, 2 ** 16))
 @settings(max_examples=12, deadline=None)
-def test_newton_edge_tiles_match_ref_ising(d, n, bm, seed):
-    """Single-channel path under sample edge tiles."""
-    _check_newton("ising", 1, 3, d, n, bm, False, seed)
+def test_newton_edge_tiles_match_ref_ising(d, n, bm, k, kb, seed):
+    """Single-channel path under sample and node edge tiles."""
+    _check_newton("ising", 1, k, d, n, bm, False, seed, kb)
 
 
 @given(d=st.integers(1, 8), n=st.integers(1, 60),
-       bm=st.sampled_from([8, 16, 128]), seed=st.integers(0, 2 ** 16))
+       bm=st.sampled_from([8, 16, 128]), k=st.integers(1, 20),
+       kb=st.sampled_from([1, 3, 8, 16]), seed=st.integers(0, 2 ** 16))
 @settings(max_examples=12, deadline=None)
-def test_newton_edge_tiles_match_ref_gaussian_weighted(d, n, bm, seed):
+def test_newton_edge_tiles_match_ref_gaussian_weighted(d, n, bm, k, kb,
+                                                       seed):
     """Constant-curvature epilogue with per-node sample weights."""
-    _check_newton("gaussian", 1, 3, d, n, bm, True, seed)
+    _check_newton("gaussian", 1, k, d, n, bm, True, seed, kb)
 
 
 # ------------------------------------------------- score-kernel edge tiles
