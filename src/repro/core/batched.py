@@ -274,11 +274,10 @@ def _bucket_design(family, X, nodes, nbrs, mask, offsets,
                    include_singleton: bool):
     """Build the channelized (k, C, d, n) bucket design + targets/masks.
 
-    Shared by the plain and proximal bucket solvers. Returns
-    ``(Zb, xi, base, cmask)``: per-channel stacked designs, node samples,
-    fixed-singleton block offsets folded into ``base`` (k, C, n), and the
-    d-length coordinate mask (all channels of a coordinate share one mask
-    entry). ``offsets``: (k, C) fixed singleton blocks.
+    Returns ``(Zb, xi, base, cmask)``: per-channel stacked designs, node
+    samples, fixed-singleton block offsets folded into ``base`` (k, C, n),
+    and the d-length coordinate mask (all channels of a coordinate share
+    one mask entry). ``offsets``: (k, C) fixed singleton blocks.
     """
     C = family.block_dim
     # (n, k, deg_pad, C): family features of the gathered neighbor values
@@ -310,8 +309,8 @@ def _flat_coord_mask(cmask: jnp.ndarray, C: int) -> jnp.ndarray:
 
 
 def _channel_ops(family, Zb, base, xi, sw, weighted, denom, terms=None):
-    """Channelized-GLM contraction closures shared by the plain and proximal
-    bucket solvers, all in the flat coordinate-major (k, d*C) layout.
+    """Channelized-GLM contraction closures of the bucket solver, all in
+    the flat coordinate-major (k, d*C) layout.
 
     C == 1 (Ising/Gaussian) keeps the seed's single-channel matmul forms —
     XLA contracts them noticeably faster than the general channelized
@@ -409,7 +408,7 @@ def _bucket_newton(family, Zb, base, xi, sw, weighted, denom):
 
 
 @_float32_contractions
-def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
+def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw, prox,
                        include_singleton: bool, n_iter: int,
                        weighted: bool = False, guarded: bool = False,
                        family=ISING, tol: float = 2e-6,
@@ -420,19 +419,30 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
 
     X: (n, p) samples; nodes: (k,); nbrs: (k, deg_pad); mask: (k, deg_pad);
     offsets: (k, C) fixed singleton blocks (used when
-    include_singleton=False); W0: (k, d*C) Newton warm start (zeros for a
+    include_singleton=False); W0: (k, d*C) Newton start (zeros for a
     cold fit); sw: (k, n) per-node sample weights, only read when
     ``weighted`` — a 0/1 prefix mask lets each node of the bucket see a
     different prefix of a shared streaming pool at fixed array shapes.
     ``family`` (static) supplies the closed-form per-channel score and
     curvature; the Ising default reproduces the seed engine exactly.
 
+    ``prox`` (None for a local fit) is the ADMM primal update's ``(lam,
+    rho, tbar)``, each (k, d*C), zero on padded coordinates: each node then
+    maximizes ``l^i(w) - lam'w - sum_a rho_a (w_a - tbar_a)^2 / 2`` (as
+    :func:`repro.core.admm._prox_solve`). The gradient shifts by ``-lam -
+    rho*(w - tbar)``, the Hessian by ``-diag(rho)`` (still uniformly
+    negative definite), the guard scores the penalized objective, and the
+    solve returns W alone (static: no sandwich).
+
     Designs live in (k, C, d, n) layout so the per-iteration Hessian is one
-    batched einsum contracting over the contiguous sample axis; for C = 1
-    the channel axes collapse and nothing is wasted. ``tol`` (on the damped
-    step's inf-norm) is chosen just above the float32 jitter floor: iterating
-    past it only bounces around the optimum, which is all the seed's fixed
-    40-iteration schedule does after convergence.
+    batched einsum contracting over the contiguous sample axis. One
+    stopping rule serves both: iterate while under ``n_iter`` and some
+    row's damped step (inf-norm) is above ``tol``, just above the float32
+    jitter floor. A bucket of more than ``_SPLIT_ROWS`` rows stops once no
+    more than ``_STRAGGLER_ROWS`` rows step above ``tol``; those rows alone
+    then finish under the same budget, so one slow site does not cost the
+    whole bucket its extra iterations. ``guarded`` (static: the start may
+    be saturated) backs an untrusted step with a backtracking search.
 
     Returns (W, H, J, S, I, D) with leading bucket dimension k and flat
     parameter dimension d*C (coordinate-major blocks): W the estimates, H
@@ -442,18 +452,18 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     (k,) sample counts the sums average over. Padded coordinates are
     exactly zero in W and carry a ``-1`` placeholder diagonal in the
     Newton system. ``I`` is the (k,) Newton-iteration count the damped
-    solve actually used (bucket-wide — the loop stops when every node's
-    step converged — broadcast per node so it shards like the other
-    outputs). A node whose weights sum to zero (nothing observed yet)
-    stays at W0 untouched by data: its gradient vanishes and the guarded
-    denominator keeps it finite.
+    solve actually used (bucket-wide, the straggler finish included,
+    broadcast per node so it shards like the other outputs). A node whose
+    weights sum to zero (nothing observed yet) stays at W0 untouched by
+    data: its gradient vanishes and the guarded denominator keeps it
+    finite.
 
     ``axis_name`` names the mesh axis when this runs as one shard of a
-    bucket: the convergence test then takes the step's max over all
-    shards, so every shard runs the bucket-wide iteration count. A shard
-    of more than ``_SPLIT_ROWS`` rows finishes its own slowest rows alone,
-    so results agree across device counts to Newton tolerance, and exactly
-    on one device.
+    bucket: the stopping rule then takes the step's max and the live row
+    count over all shards, so every shard runs the bucket-wide iteration
+    count. A shard of more than ``_SPLIT_ROWS`` rows finishes its own
+    slowest rows alone, so results agree across device counts to Newton
+    tolerance, and exactly on one device.
     """
     n = X.shape[0]
     Zb, xi, base, cmask = _bucket_design(family, X, nodes, nbrs, mask,
@@ -473,13 +483,24 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     else:
         denom = jnp.full((k,), float(n), cdtype)
 
-    def iterate(Zb, base, xi, sw, denom, pad_diag, W, it, delta, stop):
+    def iterate(Zb, base, xi, sw, denom, pad_diag, prox, W, it, delta,
+                stop):
         """Damped Newton on these rows from W: while under the budget, some
         row's step is above ``tol`` and more than ``stop`` rows are."""
-        objective = _channel_ops(family, Zb, base, xi, sw, weighted,
-                                 denom)[3]
+        loglik = _channel_ops(family, Zb, base, xi, sw, weighted, denom)[3]
         newton_stats = _bucket_newton(family, Zb, base, xi, sw, weighted,
                                       denom)
+        if prox is None:
+            objective = loglik
+        else:
+            lam, rho, tbar = prox
+            rho_diag = rho[:, :, None] * eye[None, :, :]
+
+            def objective(Ws):
+                # (c, k): penalized criterion for a stack of candidates
+                pen = (lam[None] * Ws).sum(axis=2) \
+                    + 0.5 * (rho[None] * (Ws - tbar[None]) ** 2).sum(axis=2)
+                return loglik(Ws) - pen
 
         def cond(carry):
             _, it, delta, live, _ = carry
@@ -489,8 +510,11 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
             W, it, _, _, _ = carry
             g_raw, K_raw = newton_stats(W)           # fused score + Gram
             g = g_raw / denom[:, None]
-            H = -K_raw / denom[:, None, None] \
-                - ridge * eye[None, :, :] - pad_diag
+            H = -K_raw / denom[:, None, None]
+            if prox is not None:
+                g = g - lam - rho * (W - tbar)
+                H = H - rho_diag
+            H = H - ridge * eye[None, :, :] - pad_diag
             dirn = _gauss_jordan_solve(H, g[..., None])[..., 0]  # (k, dC)
             # an untrusted direction: non-finite (curvature underflow at a
             # saturated point makes the solve blow up) or clipped (outside
@@ -509,7 +533,7 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
                 # (a period-2 cycle), and a near-singular Hessian can make
                 # the direction useless at any scale. Guard with a per-node
                 # backtracking search over Newton + gradient candidates on
-                # the concave CL objective. Only warm-started solves compile
+                # the concave objective. Only warm-started solves compile
                 # this branch: the pathologies need a saturated starting
                 # point, and cold starts from zero (the benchmarked hot
                 # path) never produce one.
@@ -531,21 +555,19 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
                  jnp.full(W.shape[:1], jnp.inf, cdtype))
         return jax.lax.while_loop(cond, newton_step, start)
 
-    # A large bucket iterates whole until no more than _STRAGGLER_ROWS rows
-    # still step above tol; those rows alone then finish under the same
-    # budget, so one slow site (a large or barely identified local MLE)
-    # no longer costs all the bucket's rows its extra iterations.
-    rows = (Zb, base, xi, sw, denom, pad_diag)
+    rows = (Zb, base, xi, sw, denom, pad_diag, prox)
     split = k > _SPLIT_ROWS
     W, iters, delta, _, row = iterate(
         *rows, W0, 0, jnp.inf, _STRAGGLER_ROWS if split else -1)
     if split:
         idx = jax.lax.top_k(row, _STRAGGLER_ROWS)[1]
-        few = [a[idx] for a in rows]
+        few = list(jax.tree.map(lambda a: a[idx], rows))
         if not weighted:
             few[3] = sw                              # never read
         Wf, iters, _, _, _ = iterate(*few, W[idx], iters, delta, -1)
         W = W.at[idx].set(Wf)
+    if prox is not None:
+        return W
     I = jnp.full((k,), iters, dtype=jnp.int32)
 
     # sandwich sums at W_hat, compensated (float32 hi + lo) so that the
@@ -579,19 +601,65 @@ def _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
     return W, H2, J2, S, I, denom
 
 
+def _bucket_program(mesh, X, nodes, nbrs, mask, offsets, W0, sw, prox,
+                    **static):
+    """:func:`_solve_bucket_impl` on one device (``mesh`` None), or with
+    each device solving a contiguous slice of the bucket's rows (padded by
+    the caller, :func:`_pad_bucket_rows`) against the replicated samples;
+    the stopping rule's two scalars are the only collectives. A one-device
+    mesh computes op for op what no mesh does."""
+    if mesh is None:
+        return _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
+                                  prox, **static)
+    data = P("data")
+    # every per-row array splits along the data axis; X and an
+    # unweighted solve's (1, 1) weight stand-in are replicated
+    return jax.shard_map(
+        functools.partial(_solve_bucket_impl, **static, axis_name="data"),
+        mesh=mesh,
+        in_specs=(P(), data, data, data, data, data,
+                  data if static["weighted"] else P(), data),
+        out_specs=data,
+        check_vma=False,
+    )(X, nodes, nbrs, mask, offsets, W0, sw, prox)
+
+
 @functools.partial(jax.jit,
                    static_argnames=("include_singleton", "n_iter", "weighted",
-                                    "guarded", "family", "want_influence"))
+                                    "guarded", "family", "want_influence",
+                                    "mesh"))
 @jax.named_scope("bucket_solve")
 def _solve_bucket(X, nodes, nbrs, mask, offsets, W0, sw,
                   include_singleton: bool, n_iter: int, weighted: bool = False,
                   guarded: bool = False, family=ISING, tol: float = 2e-6,
                   ridge: float = 1e-8, max_step: float = 5.0,
-                  want_influence: bool = True):
-    """Single-device bucket solve (jitted :func:`_solve_bucket_impl`)."""
-    return _solve_bucket_impl(X, nodes, nbrs, mask, offsets, W0, sw,
-                              include_singleton, n_iter, weighted, guarded,
-                              family, tol, ridge, max_step, want_influence)
+                  want_influence: bool = True, mesh=None):
+    """The local fit's bucket solve (jitted :func:`_solve_bucket_impl`,
+    on ``mesh`` when given)."""
+    return _bucket_program(
+        mesh, X, nodes, nbrs, mask, offsets, W0, sw, None,
+        include_singleton=include_singleton, n_iter=n_iter,
+        weighted=weighted, guarded=guarded, family=family, tol=tol,
+        ridge=ridge, max_step=max_step, want_influence=want_influence)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("include_singleton", "n_iter", "weighted",
+                                    "family", "mesh"))
+@jax.named_scope("prox_bucket_solve")
+def _solve_bucket_prox(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho, tbar,
+                       include_singleton: bool, n_iter: int,
+                       weighted: bool = False, family=ISING, tol: float = 2e-6,
+                       ridge: float = 1e-8, max_step: float = 5.0,
+                       mesh=None):
+    """The ADMM primal update's bucket solve: :func:`_solve_bucket_impl`
+    with the prox term, guarded (it starts from a consensus view or the
+    previous iterate, never from zero); returns W."""
+    return _bucket_program(
+        mesh, X, nodes, nbrs, mask, offsets, W0, sw, (lam, rho, tbar),
+        include_singleton=include_singleton, n_iter=n_iter,
+        weighted=weighted, guarded=True, family=family, tol=tol,
+        ridge=ridge, max_step=max_step)
 
 
 def _mesh_data_size(mesh) -> int:
@@ -603,90 +671,50 @@ def _mesh_data_size(mesh) -> int:
     return int(mesh.shape["data"])
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("include_singleton", "n_iter", "weighted",
-                                    "guarded", "family", "mesh",
-                                    "want_influence"))
-@jax.named_scope("bucket_solve")
-def _solve_bucket_sharded(X, nodes, nbrs, mask, offsets, W0, sw,
-                          include_singleton: bool, n_iter: int,
-                          weighted: bool = False, guarded: bool = False,
-                          family=ISING, mesh=None,
-                          want_influence: bool = True):
-    """Mesh-sharded bucket solve: nodes split along the ``data`` axis.
-
-    The bucket's k per-node problems are embarrassingly parallel, so each
-    device solves its contiguous slice of the (padded) node axis against
-    the replicated sample pool. The only collective is one scalar max per
-    Newton iteration, which keeps every shard on the bucket-wide stopping
-    iteration (so the result does not depend on the device count). On a
-    one-device
-    mesh (the host mesh) the single shard is the whole bucket and the
-    computation is identical to :func:`_solve_bucket` op for op, which is
-    what makes the single-device fallback numerically exact. The caller
-    pads the node axis to a multiple of the shard count
-    (:func:`_pad_bucket_rows`).
-    """
-    body = functools.partial(
-        _solve_bucket_impl, include_singleton=include_singleton,
-        n_iter=n_iter, weighted=weighted, guarded=guarded, family=family,
-        want_influence=want_influence, axis_name="data")
-    data = P("data")
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), data, data, data, data, data,
-                  data if weighted else P()),
-        out_specs=(data, data, data, data, data, data),
-        check_vma=False,
-    )(X, nodes, nbrs, mask, offsets, W0, sw)
-
-
 def _pad_bucket_rows(shards: int, *arrays):
     """Zero-pad each array's leading (bucket-node) axis to a multiple of
     ``shards`` so shard_map can split it evenly. Padded rows are inert
     dummy problems (zero design mask / zero weights) whose results the
     caller slices off."""
-    k = arrays[0].shape[0]
-    pad = (-k) % shards
+    pad = (-arrays[0].shape[0]) % shards
     if pad == 0:
         return arrays
-    out = []
-    for a in arrays:
-        widths = [(0, pad)] + [(0, 0)] * (a.ndim - 1)
-        out.append(jnp.pad(a, widths))
-    return tuple(out)
+    return tuple(jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+                 for a in arrays)
+
+
+def _compile_count(solve) -> int:
+    probe = getattr(solve, "_cache_size", None)
+    return int(probe()) if callable(probe) else -1
 
 
 def bucket_compile_count() -> int:
-    """Bucket-solver compilations since the last ``clear_cache()``, summed
-    over the plain AND mesh-sharded fit solvers — so compile-reuse
-    invariants (cold == #buckets, warm == 0) hold for mesh-policy sessions
-    too, not just the single-program path.
+    """Fit bucket-solver compilations since the last ``clear_cache()``, a
+    mesh's included — so compile-reuse invariants (cold == #buckets,
+    warm == 0) hold for mesh-policy sessions too.
 
     Counts across every graph / family / ``include_singleton`` variant
     solved so far, so callers asserting "compiles == #buckets" should clear
     the caches first. Returns -1 if the (private) jit cache probe
     disappears in a future JAX.
     """
-    total = 0
-    for fn in (_solve_bucket, _solve_bucket_sharded):
-        probe = getattr(fn, "_cache_size", None)
-        if not callable(probe):
-            return -1
-        total += int(probe())
-    return total
+    return _compile_count(_solve_bucket)
+
+
+def prox_compile_count() -> int:
+    """Proximal-solver compilations — the ADMM twin of
+    :func:`bucket_compile_count`, used for the joint verb's compile-time
+    split. Returns -1 if the jit-cache probe is gone."""
+    return _compile_count(_solve_bucket_prox)
 
 
 def clear_bucket_solver_caches() -> None:
-    """Reset the bucket-solver compile caches — fit AND proximal, plain
-    and mesh-sharded — so :func:`bucket_compile_count` and
-    :func:`prox_compile_count` restart from zero — what tests and
-    benches asserting the absolute "compiles == #buckets" invariant call
-    first."""
+    """Reset the fit and proximal bucket-solver compile caches, so
+    :func:`bucket_compile_count` and :func:`prox_compile_count` restart
+    from zero — what tests and benches asserting the absolute "compiles
+    == #buckets" invariant call first."""
     _solve_bucket.clear_cache()
-    _solve_bucket_sharded.clear_cache()
     _solve_bucket_prox.clear_cache()
-    _solve_bucket_prox_sharded.clear_cache()
 
 
 def _bucket_weights(sample_weight, nodes: np.ndarray, n: int):
@@ -700,19 +728,90 @@ def _bucket_weights(sample_weight, nodes: np.ndarray, n: int):
     return sample_weight[jnp.asarray(nodes)]
 
 
-def _bucket_warm_start(warm_start, b: DegreeBucket, dC: int, lead: int,
-                       C: int, dtype) -> jnp.ndarray:
-    """Stack per-node warm-start thetas into the bucket's padded (k, d*C)."""
-    W0 = np.zeros((len(b.nodes), dC), dtype=np.float32)
-    if warm_start is not None:
-        degs = b.mask.sum(axis=1).astype(np.int64)
-        for row, i in enumerate(b.nodes):
-            w = warm_start[int(i)]
-            if w is None:
-                continue
+def _bucket_rows(vectors, b: DegreeBucket, dC: int, lead: int, C: int,
+                 fill=None) -> np.ndarray:
+    """Per-node vectors, one per bucket row (``None`` entries allowed), in
+    the bucket's padded (k, d*C) float32 rows; a ``None`` row keeps
+    ``fill``'s row (zeros without ``fill``)."""
+    rows = np.zeros((len(b.nodes), dC), np.float32) if fill is None \
+        else np.array(fill, copy=True)
+    degs = b.mask.sum(axis=1).astype(np.int64)
+    for row, v in enumerate(vectors):
+        if v is not None:
             di = (lead + int(degs[row])) * C
-            W0[row, :di] = np.asarray(w, dtype=np.float32)[:di]
-    return jnp.asarray(W0, dtype=dtype)
+            rows[row, :di] = np.asarray(v, dtype=np.float32)[:di]
+    return rows
+
+
+def _solved_buckets(span: str, solve, prep, graph: Graph, X, theta_fixed,
+                    sample_weight, family, include_singleton: bool, mesh,
+                    rec, stats: Optional[dict], **static):
+    """The per-bucket loop of both verbs: prepare, solve and fetch each
+    degree bucket, yielding ``(bucket, outputs)`` fetched and cut to the
+    bucket's rows (the fit's Newton counts left out: telemetry reads them).
+
+    ``solve`` (:func:`_solve_bucket` or :func:`_solve_bucket_prox`) gets
+    ``static``; ``span`` names its span and ``engine.d2h_bytes`` site.
+    ``prep(bucket, dC)`` returns the Newton start on the device and the
+    arrays the solve takes after ``sw``. Telemetry and ``stats`` as
+    :func:`fit_all_local_batched` describes."""
+    C = family.block_dim
+    if theta_fixed is None:
+        theta_fixed = jnp.zeros(family.n_params(graph), X.dtype)
+    theta_fixed = jnp.asarray(theta_fixed)
+    node_tf = theta_fixed[: graph.p * C].reshape(graph.p, C)
+    n = X.shape[0]
+    lead = 1 if include_singleton else 0
+    weighted = sample_weight is not None
+    for b in degree_buckets(graph):
+        k = len(b.nodes)
+        with rec.span("bucket_prep", deg_pad=b.deg_pad, k=k):
+            offsets = node_tf[jnp.asarray(b.nodes)]
+            sw = _bucket_weights(sample_weight, b.nodes, n)
+            W0, terms = prep(b, (b.deg_pad + lead) * C)
+            if sw is None:
+                sw = jnp.ones((1, 1), _solver_dtype(X.dtype))  # never read
+        if stats is not None:
+            c0 = _compile_count(solve)
+            t0 = time.perf_counter()
+        with rec.span(span, deg_pad=b.deg_pad, k=k):
+            rows = (jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
+                    jnp.asarray(b.mask), offsets, W0,
+                    *(jnp.asarray(t) for t in terms))
+            if mesh is not None:
+                shards = _mesh_data_size(mesh)
+                rows = _pad_bucket_rows(shards, *rows)
+                if weighted:
+                    sw = _pad_bucket_rows(shards, sw)[0]
+            outs = solve(X, *rows[:5], sw, *rows[5:], weighted=weighted,
+                         family=family, include_singleton=include_singleton,
+                         mesh=mesh, **static)
+            outs = outs if isinstance(outs, tuple) else (outs,)
+            if mesh is not None and rec.enabled:
+                # bucket rows (padding included) each device solved
+                for s in outs[0].addressable_shards:
+                    rec.observe("engine.shard_rows", int(s.data.shape[0]),
+                                deg_pad=b.deg_pad, device=int(s.device.id))
+            if rec.enabled:
+                # the Newton iteration counts, fetched for telemetry alone
+                # (below, outside the span), join the bucket's byte count
+                nbytes = sum(int(a.nbytes) for a in outs)
+            iters = outs[4] if len(outs) > 1 else None   # the fit's I
+            host = [np.asarray(a)[:k] for a in outs if a is not iters]
+        if stats is not None:
+            # the np.asarray conversions above block on the device work, so
+            # dt covers trace+compile+execute for a compiling dispatch
+            dt = time.perf_counter() - t0
+            stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
+            if _compile_count(solve) > c0 >= 0:
+                stats["compile_s"] = stats.get("compile_s", 0.0) + dt
+        if rec.enabled:
+            if iters is not None:
+                rec.observe("engine.newton_iters",
+                            int(np.max(np.asarray(iters)[:k])),
+                            deg_pad=b.deg_pad)
+            rec.inc(D2H_BYTES, nbytes, site=span)
+        yield b, host
 
 
 def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
@@ -744,11 +843,11 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
         then converge in a couple of damped steps.
 
     Scale-out: ``mesh`` (a :func:`jax.make_mesh` mesh with a ``data`` axis,
-    e.g. from :mod:`repro.launch.mesh`) runs every bucket solve through
-    :func:`_solve_bucket_sharded` — bucket nodes sharded along the ``data``
-    axis, sample pool replicated. On a one-device mesh the sharded path is
-    numerically identical to the default path; ``mesh=None`` keeps the
-    plain single-program solve.
+    e.g. from :mod:`repro.launch.mesh`) is a static argument of the one
+    bucket solve, which then splits each bucket's nodes along the ``data``
+    axis with the sample pool replicated (:func:`_bucket_program`). On a
+    one-device mesh the result is numerically identical to ``mesh=None``,
+    the plain single-program solve.
 
     ``want_influence=False`` skips materializing the (n, d) per-sample
     influence stacks (``LocalFit.s`` comes back with zero rows) — only the
@@ -773,71 +872,22 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
     if family is None:
         family = ISING
     rec = NULL_RECORDER if recorder is None else recorder
-    track = stats is not None
     C = family.block_dim
-    if theta_fixed is None:
-        theta_fixed = jnp.zeros(family.n_params(graph), X.dtype)
-    theta_fixed = jnp.asarray(theta_fixed)
-    node_tf = theta_fixed[: graph.p * C].reshape(graph.p, C)
-    n = X.shape[0]
     lead = 1 if include_singleton else 0
 
+    def prep(b, dC):
+        W0 = _bucket_rows([] if warm_start is None else
+                          [warm_start[int(i)] for i in b.nodes],
+                          b, dC, lead, C)
+        return jnp.asarray(W0, dtype=_solver_dtype(X.dtype)), ()
+
     out: List[Optional[LocalFit]] = [None] * graph.p
-    for b in degree_buckets(graph):
+    for b, (W, H, J, S, D) in _solved_buckets(
+            "bucket_solve", _solve_bucket, prep, graph, X, theta_fixed,
+            sample_weight, family, include_singleton, mesh, rec, stats,
+            n_iter=n_iter, guarded=warm_start is not None,
+            want_influence=want_influence):
         k = len(b.nodes)
-        with rec.span("bucket_prep", deg_pad=b.deg_pad, k=k):
-            offsets = node_tf[jnp.asarray(b.nodes)]
-            dC = (b.deg_pad + lead) * C
-            sw = _bucket_weights(sample_weight, b.nodes, n)
-            W0 = _bucket_warm_start(warm_start, b, dC, lead, C,
-                                    _solver_dtype(X.dtype))
-            weighted = sample_weight is not None
-            if sw is None:
-                sw = jnp.ones((1, 1), _solver_dtype(X.dtype))  # never read
-        if track:
-            c0 = bucket_compile_count()
-            t0 = time.perf_counter()
-        with rec.span("bucket_solve", deg_pad=b.deg_pad, k=k):
-            if mesh is None:
-                W, H, J, S, I, D = _solve_bucket(
-                    X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
-                    jnp.asarray(b.mask), offsets, W0, sw, include_singleton,
-                    n_iter, weighted, warm_start is not None, family,
-                    want_influence=want_influence)
-            else:
-                shards = _mesh_data_size(mesh)
-                nodes_, nbrs_, mask_, offsets_, W0_ = _pad_bucket_rows(
-                    shards, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
-                    jnp.asarray(b.mask), offsets, W0)
-                sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
-                W, H, J, S, I, D = _solve_bucket_sharded(
-                    X, nodes_, nbrs_, mask_, offsets_, W0_, sw_,
-                    include_singleton, n_iter, weighted,
-                    warm_start is not None, family, mesh,
-                    want_influence=want_influence)
-                if rec.enabled:
-                    # bucket rows (padding included) each device solved
-                    for s in W.addressable_shards:
-                        rec.observe("engine.shard_rows",
-                                    int(s.data.shape[0]),
-                                    deg_pad=b.deg_pad,
-                                    device=int(s.device.id))
-            if rec.enabled:
-                # the Newton iteration counts, fetched for telemetry alone
-                # (below, outside the span), join the bucket's byte count
-                nbytes = sum(int(a.nbytes) for a in (W, H, J, S, I, D))
-            W, H, J, S, D = (np.asarray(a)[:k] for a in (W, H, J, S, D))
-        if track:
-            # the np.asarray conversions above block on the device work, so
-            # dt covers trace+compile+execute for a compiling dispatch
-            dt = time.perf_counter() - t0
-            stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
-            if bucket_compile_count() > c0 >= 0:
-                stats["compile_s"] = stats.get("compile_s", 0.0) + dt
-        if rec.enabled:
-            rec.observe("engine.newton_iters", int(np.max(np.asarray(I)[:k])),
-                        deg_pad=b.deg_pad)
-            rec.inc(D2H_BYTES, nbytes, site="bucket_solve")
         with rec.span("assemble", deg_pad=b.deg_pad, k=k):
             cmask = (np.concatenate([np.ones((k, 1), np.float32), b.mask],
                                     axis=1) if include_singleton else b.mask)
@@ -856,131 +906,6 @@ def fit_all_local_batched(graph: Graph, X: jnp.ndarray,
 
 
 # ------------------------------------------------------- proximal updates
-@_float32_contractions
-def _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho,
-                            tbar, include_singleton: bool, n_iter: int,
-                            weighted: bool = False, family=ISING,
-                            tol: float = 2e-6, ridge: float = 1e-8,
-                            max_step: float = 5.0,
-                            axis_name: Optional[str] = None):
-    """ADMM primal update for a whole degree bucket in one XLA program.
-
-    Maximizes, per node,  ``l^i(w) - lam'w - sum_a rho_a (w_a - tbar_a)^2/2``
-    (the objective of :func:`repro.core.admm._prox_solve`) with the same
-    closed-form family-dispatched Newton machinery as :func:`_solve_bucket`:
-    the prox terms only shift the gradient by ``-lam - rho*(w - tbar)`` and
-    the Hessian by ``-diag(rho)``, so the bucket stays uniformly negative
-    definite. lam, rho, tbar: (k, d*C) with zeros on padded coordinates.
-    ``axis_name`` as in :func:`_solve_bucket_impl`. Returns W only.
-    """
-    n = X.shape[0]
-    Zb, xi, base, cmask = _bucket_design(family, X, nodes, nbrs, mask,
-                                         offsets, include_singleton)
-    k, C, d, _ = Zb.shape
-    dC = d * C
-    cdtype = _solver_dtype(Zb.dtype)
-    W0 = W0.astype(cdtype)
-    eye = jnp.eye(dC, dtype=cdtype)
-    cflat = _flat_coord_mask(cmask, C)
-    pad_diag = (1.0 - cflat)[:, :, None] * eye[None, :, :]
-    rho_diag = rho[:, :, None] * eye[None, :, :]
-    if weighted:
-        denom = jnp.maximum(jnp.sum(sw, axis=1), 1.0)
-    else:
-        denom = jnp.full((k,), float(n), cdtype)
-
-    avg_loglik = _channel_ops(family, Zb, base, xi, sw, weighted, denom)[3]
-    newton_stats = _bucket_newton(family, Zb, base, xi, sw, weighted, denom)
-
-    def objective(Ws):
-        # (c, k): penalized criterion for a stack of candidate points
-        pen = (lam[None] * Ws).sum(axis=2) \
-            + 0.5 * (rho[None] * (Ws - tbar[None]) ** 2).sum(axis=2)
-        return avg_loglik(Ws) - pen
-
-    def cond(carry):
-        _, it, delta = carry
-        return (it < n_iter) & (delta > tol)
-
-    def newton_step(carry):
-        W, it, _ = carry
-        g_raw, K_raw = newton_stats(W)           # fused score + Gram
-        g = g_raw / denom[:, None] - lam - rho * (W - tbar)
-        H = -K_raw / denom[:, None, None] \
-            - rho_diag - ridge * eye[None, :, :] - pad_diag
-        dirn = _gauss_jordan_solve(H, g[..., None])[..., 0]
-        finite = jnp.all(jnp.isfinite(dirn), axis=1, keepdims=True)
-        dirn = jnp.where(finite, dirn, 0.0)
-        norm = jnp.linalg.norm(dirn, axis=1, keepdims=True)
-        untrusted = (norm > max_step) | ~finite
-        dirn = jnp.where(norm > max_step,
-                         dirn * (max_step / (norm + 1e-30)), dirn)
-
-        # same saturation guard as _solve_bucket, on the penalized objective
-        step = jax.lax.cond(
-            jnp.any(untrusted),
-            lambda: _backtrack_step(objective, W, dirn, g, max_step),
-            lambda: dirn)
-        delta = jnp.max(jnp.abs(step))
-        if axis_name is not None:
-            delta = jax.lax.pmax(delta, axis_name)
-        return W - step, it + 1, delta
-
-    W, _, _ = jax.lax.while_loop(cond, newton_step, (W0, 0, jnp.inf))
-    return W
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("include_singleton", "n_iter", "weighted",
-                                    "family"))
-@jax.named_scope("prox_bucket_solve")
-def _solve_bucket_prox(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho, tbar,
-                       include_singleton: bool, n_iter: int,
-                       weighted: bool = False, family=ISING, tol: float = 2e-6,
-                       ridge: float = 1e-8, max_step: float = 5.0):
-    """Single-device proximal bucket solve (jitted impl)."""
-    return _solve_bucket_prox_impl(X, nodes, nbrs, mask, offsets, W0, sw,
-                                   lam, rho, tbar, include_singleton, n_iter,
-                                   weighted, family, tol, ridge, max_step)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("include_singleton", "n_iter", "weighted",
-                                    "family", "mesh"))
-@jax.named_scope("prox_bucket_solve")
-def _solve_bucket_prox_sharded(X, nodes, nbrs, mask, offsets, W0, sw, lam,
-                               rho, tbar, include_singleton: bool,
-                               n_iter: int, weighted: bool = False,
-                               family=ISING, mesh=None):
-    """Mesh-sharded proximal bucket solve — the ADMM-primal twin of
-    :func:`_solve_bucket_sharded` (same data-axis node sharding, replicated
-    sample pool, one scalar max per Newton iteration)."""
-    body = functools.partial(
-        _solve_bucket_prox_impl, include_singleton=include_singleton,
-        n_iter=n_iter, weighted=weighted, family=family, axis_name="data")
-    data = P("data")
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), data, data, data, data, data,
-                  data if weighted else P(), data, data, data),
-        out_specs=data,
-        check_vma=False,
-    )(X, nodes, nbrs, mask, offsets, W0, sw, lam, rho, tbar)
-
-
-def prox_compile_count() -> int:
-    """Proximal-solver compilations (plain + mesh-sharded) — the ADMM twin
-    of :func:`bucket_compile_count`, used for the joint verb's
-    compile-time split. Returns -1 if the jit-cache probe is gone."""
-    total = 0
-    for fn in (_solve_bucket_prox, _solve_bucket_prox_sharded):
-        probe = getattr(fn, "_cache_size", None)
-        if not callable(probe):
-            return -1
-        total += int(probe())
-    return total
-
-
 def group_soft_threshold(v: np.ndarray, thr: float, block_dim: int,
                          lead: int = 1) -> np.ndarray:
     """Group soft-thresholding on a ``family.beta``-ordered local vector.
@@ -1044,89 +969,37 @@ def prox_update_batched(graph: Graph, X: jnp.ndarray,
     ``recorder`` / ``stats`` mirror :func:`fit_all_local_batched`: a
     ``bucket_prep`` and a ``prox_bucket_solve`` span and one
     ``engine.d2h_bytes`` increment per bucket, and ``stats["compile_s"]`` /
-    ``stats["dispatch_s"]`` accumulation keyed to the prox-solver caches.
+    ``stats["dispatch_s"]`` accumulation keyed to the prox-solver cache.
     """
     if family is None:
         family = ISING
     rec = NULL_RECORDER if recorder is None else recorder
-    track = stats is not None
     C = family.block_dim
-    if theta_fixed is None:
-        theta_fixed = jnp.zeros(family.n_params(graph), X.dtype)
-    theta_fixed = jnp.asarray(theta_fixed)
-    node_tf = theta_fixed[: graph.p * C].reshape(graph.p, C)
     per_node_bar = isinstance(theta_bar, (list, tuple))
     if not per_node_bar:
         theta_bar = np.asarray(theta_bar)
-    n = X.shape[0]
     lead = 1 if include_singleton else 0
 
+    def prep(b, dC):
+        nodes = [int(i) for i in b.nodes]
+        views = [theta_bar[i] if per_node_bar else theta_bar[np.asarray(
+                     family.beta(graph, i, include_singleton))]
+                 for i in nodes]
+        lam, rho = (_bucket_rows([vs[i] for i in nodes], b, dC, lead, C)
+                    for vs in (lambdas, rhos))
+        tbar = _bucket_rows(views, b, dC, lead, C)
+        # warm-start at the previous iterate where given; nodes without one
+        # (thetas0 absent or a None entry) start at their consensus view
+        W0 = _bucket_rows([] if thetas0 is None else
+                          [thetas0[i] for i in nodes], b, dC, lead, C, tbar)
+        return jnp.asarray(W0, dtype=_solver_dtype(X.dtype)), (lam, rho, tbar)
+
     out: List[Optional[np.ndarray]] = [None] * graph.p
-    for b in degree_buckets(graph):
-        k = len(b.nodes)
-        with rec.span("bucket_prep", deg_pad=b.deg_pad, k=k):
-            dC = (b.deg_pad + lead) * C
-            degs = b.mask.sum(axis=1).astype(np.int64)
-            lam = np.zeros((k, dC), dtype=np.float32)
-            rho = np.zeros((k, dC), dtype=np.float32)
-            tbar = np.zeros((k, dC), dtype=np.float32)
-            for row, i in enumerate(b.nodes):
-                i = int(i)
-                di = (lead + int(degs[row])) * C
-                lam[row, :di] = np.asarray(lambdas[i])[:di]
-                rho[row, :di] = np.asarray(rhos[i])[:di]
-                if per_node_bar:
-                    tbar[row, :di] = np.asarray(theta_bar[i])[:di]
-                else:
-                    beta = np.asarray(family.beta(graph, i,
-                                                  include_singleton))
-                    tbar[row, :di] = theta_bar[beta][:di]
-            # warm-start at the previous iterate where given; nodes without
-            # one (thetas0 absent or a None entry) start at their consensus
-            # view
-            W0 = np.array(tbar, copy=True)
-            if thetas0 is not None:
-                for row, i in enumerate(b.nodes):
-                    t0 = thetas0[int(i)]
-                    if t0 is not None:
-                        di = (lead + int(degs[row])) * C
-                        W0[row, :di] = np.asarray(t0, dtype=np.float32)[:di]
-            W0 = jnp.asarray(W0, dtype=_solver_dtype(X.dtype))
-            sw = _bucket_weights(sample_weight, b.nodes, n)
-            weighted = sample_weight is not None
-            if sw is None:
-                sw = jnp.ones((1, 1), _solver_dtype(X.dtype))
-            offsets = node_tf[jnp.asarray(b.nodes)]
-        if track:
-            c0 = prox_compile_count()
-            t0 = time.perf_counter()
-        with rec.span("prox_bucket_solve", deg_pad=b.deg_pad, k=k):
-            if mesh is None:
-                W = _solve_bucket_prox(
-                    X, jnp.asarray(b.nodes), jnp.asarray(b.nbrs),
-                    jnp.asarray(b.mask), offsets, W0, sw,
-                    jnp.asarray(lam), jnp.asarray(rho), jnp.asarray(tbar),
-                    include_singleton, n_iter, weighted, family)
-            else:
-                shards = _mesh_data_size(mesh)
-                nodes_, nbrs_, mask_, offsets_, W0_, lam_, rho_, tbar_ = \
-                    _pad_bucket_rows(shards, jnp.asarray(b.nodes),
-                                     jnp.asarray(b.nbrs),
-                                     jnp.asarray(b.mask), offsets, W0,
-                                     jnp.asarray(lam), jnp.asarray(rho),
-                                     jnp.asarray(tbar))
-                sw_ = _pad_bucket_rows(shards, sw)[0] if weighted else sw
-                W = _solve_bucket_prox_sharded(
-                    X, nodes_, nbrs_, mask_, offsets_, W0_, sw_, lam_, rho_,
-                    tbar_, include_singleton, n_iter, weighted, family, mesh)
-            if rec.enabled:
-                rec.inc(D2H_BYTES, int(W.nbytes), site="prox_bucket_solve")
-            W = np.asarray(W)[:k]
-        if track:
-            dt = time.perf_counter() - t0
-            stats["dispatch_s"] = stats.get("dispatch_s", 0.0) + dt
-            if prox_compile_count() > c0 >= 0:
-                stats["compile_s"] = stats.get("compile_s", 0.0) + dt
+    for b, (W,) in _solved_buckets(
+            "prox_bucket_solve", _solve_bucket_prox, prep, graph, X,
+            theta_fixed, sample_weight, family, include_singleton, mesh, rec,
+            stats, n_iter=n_iter):
+        degs = b.mask.sum(axis=1).astype(np.int64)
         for row, i in enumerate(b.nodes):
             di = (lead + int(degs[row])) * C
             out[int(i)] = W[row, :di].copy()

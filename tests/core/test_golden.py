@@ -2,10 +2,11 @@
 
 ``tests/core/golden_estimators.json`` (regenerated only deliberately via
 ``tools/gen_golden.py``) freezes seeded outputs of the batched local-fit
-engine and all four one-step consensus schemes on a small grid-graph Ising
-problem. Reproducing them to 1e-10 catches *silent* numeric drift — a
-changed einsum association, a reordered reduction, an accidental dtype
-downgrade — that tolerance-based correctness tests would absorb.
+engine, all four one-step consensus schemes and the batched ADMM primal
+update on a small grid-graph Ising problem. Reproducing them to 1e-10
+catches *silent* numeric drift — a changed einsum association, a
+reordered reduction, an accidental dtype downgrade — that
+tolerance-based correctness tests would absorb.
 """
 import json
 import os
@@ -41,12 +42,15 @@ def test_scenario_is_the_frozen_one(golden, recomputed):
                                golden["theta_star"], atol=ATOL)
 
 
-def test_batched_local_fits_bitstable(golden, recomputed):
-    assert len(recomputed["local_theta"]) == len(golden["local_theta"])
-    for got, want in zip(recomputed["local_theta"], golden["local_theta"]):
-        np.testing.assert_allclose(got, want, atol=ATOL)
-    for got, want in zip(recomputed["local_vdiag"], golden["local_vdiag"]):
-        np.testing.assert_allclose(got, want, atol=ATOL)
+@pytest.mark.parametrize("key", ["local_theta", "local_vdiag", "prox_flat",
+                                 "prox_views"])
+def test_batched_local_fits_bitstable(golden, recomputed, key):
+    """Per-node outputs of the batched engine: the local fits' thetas and
+    variance diagonals, and the ADMM primal update against a flat
+    consensus iterate and against warm-started per-node views."""
+    assert len(recomputed[key]) == len(golden[key])
+    for got, want in zip(recomputed[key], golden[key]):
+        np.testing.assert_allclose(got, want, atol=ATOL, err_msg=key)
 
 
 def test_combine_all_schemes_bitstable(golden, recomputed):

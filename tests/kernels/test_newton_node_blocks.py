@@ -227,18 +227,31 @@ def _fit_both_paths(monkeypatch, fit):
 
 
 @pytest.mark.parametrize("case", ["tree", "split_lattice", "weighted",
-                                  "prox"])
+                                  "prox", "prox_split"])
 def test_bucket_solves_through_the_kernel_match(monkeypatch, case):
     """Whole fits with the kernel prepared once per bucket solve agree
     with the reference path to Newton tolerance: hub and leaf buckets of
     a scale-free tree (both forms), a lattice bucket split into its
-    slowest rows, per-node sample weights, and the ADMM prox update."""
+    slowest rows, per-node sample weights, and the ADMM prox update, also
+    on a 32 x 32 lattice's one 1024-row bucket, where it finishes its
+    slowest rows alone and still meets the per-node ADMM primal update."""
     from repro.core import batched
+    from repro.core.admm import _prox_solve
+    from repro.core.estimators import node_design
     from repro.core.graphs import grid_graph, scale_free_graph
     n = 96
-    g = grid_graph(12, 12) if case == "split_lattice" else \
-        scale_free_graph(40, seed=0)
-    X = jnp.sign(jax.random.normal(jax.random.PRNGKey(3), (n, g.p)))
+    if case == "split_lattice":
+        g = grid_graph(12, 12)
+    elif case == "prox_split":
+        g = grid_graph(32, 32)
+    else:
+        g = scale_free_graph(40, seed=0)
+    X = jax.random.normal(jax.random.PRNGKey(3), (n, g.p))
+    if case == "prox_split":
+        # every seventh site nearly always +1: weakly penalized, its fit
+        # converges slowly and is among the rows finished alone
+        X = X + 3.0 * (jnp.arange(g.p) % 7 == 0)
+    X = jnp.sign(X)
     if case == "split_lattice":
         monkeypatch.setattr(batched, "_SPLIT_ROWS", 100)
     if case == "prox":
@@ -249,6 +262,15 @@ def test_bucket_solves_through_the_kernel_match(monkeypatch, case):
 
         def fit():
             return batched.prox_update_batched(g, X, tbar, lam, rho)
+    elif case == "prox_split":
+        rng = np.random.RandomState(5)
+        tbar = 0.1 * rng.randn(g.p + len(g.edges))
+        lam = [0.05 * rng.randn(len(g.beta(i))) for i in range(g.p)]
+        rho = [np.full(len(g.beta(i)), 0.01) for i in range(g.p)]
+
+        def fit():
+            return batched.prox_update_batched(g, X, tbar, lam, rho,
+                                               n_iter=30)
     else:
         sw = (jax.random.uniform(jax.random.PRNGKey(4), (g.p, n)) < 0.8
               ).astype(jnp.float32) if case == "weighted" else None
@@ -262,3 +284,13 @@ def test_bucket_solves_through_the_kernel_match(monkeypatch, case):
     for a, b in zip(default, kernel):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=0,
                                    atol=1e-5)
+    if case == "prox_split":
+        assert [len(b.nodes) for b in batched.degree_buckets(g)] == [g.p]
+        assert g.p > split
+        for i, got in enumerate(default):
+            beta = np.asarray(g.beta(i))
+            ref = _prox_solve(node_design(g, X, i), X[:, i], 0.0,
+                              jnp.asarray(lam[i]), jnp.asarray(rho[i]),
+                              jnp.asarray(tbar[beta]),
+                              jnp.asarray(tbar[beta]), True, 30)
+            np.testing.assert_allclose(got, np.asarray(ref), atol=1e-5)

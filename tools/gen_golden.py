@@ -1,7 +1,9 @@
 """Regenerate the golden-value regression fixture for the estimator stack.
 
-Freezes seeded outputs of ``fit_all_local_batched`` (per-node local thetas)
-and ``consensus.combine`` (all four one-step weighting schemes) on a small
+Freezes seeded outputs of ``fit_all_local_batched`` (per-node local thetas),
+``consensus.combine`` (all four one-step weighting schemes) and the batched
+ADMM primal update ``prox_update_batched`` (against a flat consensus
+iterate, and against per-node consensus views with warm starts) on a small
 grid-graph Ising problem into ``tests/core/golden_estimators.json``;
 ``tests/core/test_golden.py`` asserts future runs reproduce them to 1e-10,
 catching silent numeric drift in refactors of the batched engine, the
@@ -30,7 +32,9 @@ OUT = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 #: the frozen scenario — change it only together with the fixture
 CONFIG = {"graph": "grid_graph(2, 3)", "model_key": 11, "sample_key": 12,
           "sigma_pair": 0.5, "sigma_single": 0.3, "n": 400,
-          "schemes": ["uniform", "diagonal", "optimal", "max"]}
+          "schemes": ["uniform", "diagonal", "optimal", "max"],
+          "prox": {"seed": 13, "rho": 0.5, "lam_scale": 0.05,
+                   "view_scale": 0.1, "n_iter": 15}}
 
 
 def compute():
@@ -40,6 +44,7 @@ def compute():
     X = C.exact_sample(m, CONFIG["n"],
                        jax.random.PRNGKey(CONFIG["sample_key"]))
     fits = C.fit_all_local(g, X, method="batched")
+    combined = {sch: C.combine(g, fits, sch) for sch in CONFIG["schemes"]}
     payload = {
         "config": CONFIG,
         "theta_star": np.asarray(m.theta, dtype=np.float64).tolist(),
@@ -47,12 +52,31 @@ def compute():
                         for f in fits],
         "local_vdiag": [np.diag(f.V).astype(np.float64).tolist()
                         for f in fits],
-        "combine": {
-            sch: C.combine(g, fits, sch).astype(np.float64).tolist()
-            for sch in CONFIG["schemes"]
-        },
+        "combine": {sch: np.asarray(t, dtype=np.float64).tolist()
+                    for sch, t in combined.items()},
     }
+    payload.update(_prox_updates(g, X, fits, combined["diagonal"]))
     return payload
+
+
+def _prox_updates(g, X, fits, theta_bar):
+    """The ADMM primal update from the diagonal combine: once against the
+    flat iterate, once against seeded per-node views warm-started at the
+    local fits."""
+    cfg = CONFIG["prox"]
+    rng = np.random.RandomState(cfg["seed"])
+    betas = [np.asarray(g.beta(i)) for i in range(g.p)]
+    lambdas = [cfg["lam_scale"] * rng.randn(len(b)) for b in betas]
+    rhos = [np.full(len(b), cfg["rho"]) for b in betas]
+    views = [theta_bar[b] + cfg["view_scale"] * rng.randn(len(b))
+             for b in betas]
+    flat = C.prox_update_batched(g, X, theta_bar, lambdas, rhos,
+                                 n_iter=cfg["n_iter"])
+    viewed = C.prox_update_batched(g, X, views, lambdas, rhos,
+                                   thetas0=[f.theta for f in fits],
+                                   n_iter=cfg["n_iter"])
+    return {key: [np.asarray(t, dtype=np.float64).tolist() for t in out]
+            for key, out in (("prox_flat", flat), ("prox_views", viewed))}
 
 
 def main():
